@@ -34,10 +34,32 @@ def qrange(bits: int, *, unsigned: bool = False) -> tuple[int, int]:
 
 def absmax_scale(x: jax.Array, bits: int, *, axis=None, unsigned: bool = False,
                  eps: float = 1e-8) -> jax.Array:
-    """Calibrate step size from the abs-max of ``x`` (keepdims over ``axis``)."""
+    """Calibrate step size from the abs-max of ``x`` (keepdims over ``axis``).
+
+    The step is f32 whatever ``x``'s dtype: scale arithmetic in bf16 would
+    leave each rounding to the compiler (XLA may keep a bf16 product in f32
+    inside one fusion and round it at another's boundary), so the codes and
+    epilogues of two backends could differ by an ulp of the step.
+    """
     _, qmax = qrange(bits, unsigned=unsigned)
     amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
-    return jnp.maximum(amax, eps) / qmax
+    return pin(jnp.maximum(amax.astype(jnp.float32), eps) / qmax)
+
+
+def pin(step: jax.Array) -> jax.Array:
+    """A step size as a value XLA may not re-derive.
+
+    XLA's algebraic simplifier folds constant factors across a product
+    chain: ``(amax * (1/qmax)) * (softmax_scale * log2e)`` becomes ``amax *
+    c``, rounded once instead of twice.  Whether it folds depends on the ops
+    between the two constants (a gather in one program, none in another),
+    so the Pallas and XLA programs would dequantize on steps one ulp apart
+    (seen on a v5e).  It also reorders a chain to scale the smaller operand
+    first: ``(c * q_step) * k_step`` became ``(c * k_step) * q_step`` in
+    one program only.  Behind an optimization barrier the step is rounded
+    where it is computed, in every program.
+    """
+    return jax.lax.optimization_barrier(step)
 
 
 def quantize(x: jax.Array, delta: jax.Array, bits: int, *,
@@ -162,7 +184,7 @@ def quantize_tensor(x: jax.Array, bits: int, *, scale: Optional[jax.Array] = Non
     if scale is None:
         scale = absmax_scale(x, bits, unsigned=unsigned)
     return QTensor(quantize(x, scale, bits, unsigned=unsigned),
-                   jnp.asarray(scale, x.dtype), bits, unsigned)
+                   jnp.asarray(scale, jnp.float32), bits, unsigned)
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,10 @@
                ~= (1 + r) * 2^floor(x),   x = s*log2(e)*qk, r = x - floor(x)
 
 i.e. a piecewise-linear-in-mantissa approximation of 2^x realized in hardware
-as "(r+1) << floor(x)".  On TPU this maps to a vectorized ldexp on the VPU.
+as "(r+1) << floor(x)".  On TPU the shift is built from exponent bits on the
+VPU (:func:`pow2`), so XLA and the Pallas kernels emit the same bits: both
+compilers lower a float ``2^k`` differently (``exp(k ln 2)`` against a
+native pow2), and only the bit construction is exact in both.
 Maximum relative error of (1+r)*2^floor(x) vs 2^x is max_r (1+r)/2^r - 1
 ~= 6.15% at r = 1/ln2 - 1; mean error ~2.6%.
 
@@ -21,11 +24,41 @@ import jax.numpy as jnp
 LOG2E = 1.4426950408889634
 
 
+def pow2(k: jax.Array) -> jax.Array:
+    """Exact f32 ``2^k`` for integer-valued ``k``, built from exponent bits.
+
+    Below the normal range (k < -126) the result is 0, above it (k > 127)
+    it is inf.
+    """
+    e = jnp.clip(k, -127.0, 128.0).astype(jnp.int32) + 127
+    return jax.lax.bitcast_convert_type(e << 23, jnp.float32)
+
+
 def exp2_shift(x: jax.Array) -> jax.Array:
     """(1 + r) * 2^floor(x): the paper's shift-exp approximation of 2^x."""
     f = jnp.floor(x)
     r = x - f
-    return jnp.ldexp(1.0 + r, f.astype(jnp.int32))
+    return (1.0 + r) * pow2(f).astype(r.dtype)
+
+
+def tree_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis in one fixed pairwise order (keeps the axis).
+
+    Each step adds lanes ``i`` and ``i + h`` (``h`` the largest power of two
+    below the width); lanes without a partner pass through.  That is the
+    order of a zero-padded power-of-two tree, so any zero padding of ``x``
+    gives the same bits.  The integer attention paths accumulate Sigma
+    with it: ``jnp.sum`` leaves the order to the compiler, which differs
+    between XLA and a Pallas kernel (and between two XLA fusions), and a
+    one-ulp Sigma difference moves every dequantized output.
+    """
+    w = x.shape[-1]
+    while w > 1:
+        h = 1 << ((w - 1).bit_length() - 1)
+        lo = x[..., :w - h] + x[..., h:w]
+        x = lo if 2 * h == w else jnp.concatenate([lo, x[..., w - h:h]], -1)
+        w = h
+    return x
 
 
 def exp_shift(x: jax.Array) -> jax.Array:

@@ -13,8 +13,10 @@ Backend selection (checked at trace time, so switching requires a re-trace):
 
 1. ``QuantConfig.backend`` — per-model override ("xla" | "pallas" | None);
 2. ``REPRO_KERNEL_BACKEND`` env var / :func:`set_backend` /
-   :func:`use_backend` — process-wide default (initially "xla");
-3. shape policy — even under "pallas", ops the kernels cannot express
+   :func:`use_backend` — explicit process-wide override;
+3. the platform — "pallas" when JAX's default backend is a TPU, "xla"
+   elsewhere (where the kernels could only run interpreted);
+4. shape policy — even under "pallas", ops the kernels cannot express
    (3D weight stacks, >8-bit prob grids, multi-query ring reads) fall
    back to XLA per call site.
 
@@ -42,9 +44,9 @@ stream, so a page shared from a prefix owner dequantizes on the OWNER's
 grid.  The XLA fallback (``attention_paged_xla``) gathers pages as
 *codes* — int8, or nibbles unpacked to int8 — never as floats.
 
-``REPRO_PALLAS_COMPILED=1`` runs the kernels compiled on a real TPU;
-otherwise they execute in interpret mode (correct everywhere, fast
-nowhere — which is why "xla" stays the default off-TPU).
+The kernels compile for the MXU on a TPU and run in interpret mode
+elsewhere (:func:`repro.kernels.interpret_mode`): correct everywhere, fast
+only on the chip — which is why "xla" is the default off-TPU.
 
 Parity with the XLA int path is exact (<= 1e-5) whenever one key block
 covers the row — ``attention_blocks`` / ``decode_blocks`` prefer that and
@@ -65,6 +67,7 @@ import contextlib
 import os
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -85,8 +88,10 @@ def _checked(name: str, source: str) -> str:
     return name
 
 
-_backend = [_checked(os.environ.get("REPRO_KERNEL_BACKEND", "xla"),
-                     "REPRO_KERNEL_BACKEND")]
+# None: follow the platform (see get_backend).
+_env_backend = os.environ.get("REPRO_KERNEL_BACKEND")
+_backend = [None if _env_backend is None
+            else _checked(_env_backend, "REPRO_KERNEL_BACKEND")]
 
 STATS = {"qlinear_pallas": 0, "qlinear_xla": 0,
          "attention_pallas": 0, "attention_decode_pallas": 0,
@@ -134,7 +139,11 @@ def _record_blocks(op: str, key: str, *choice: int):
 
 
 def get_backend() -> str:
-    return _backend[-1]
+    """The process-wide backend: an explicit override, else the platform's
+    ("pallas" on a TPU, "xla" elsewhere)."""
+    if _backend[-1] is not None:
+        return _backend[-1]
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def set_backend(name: str):
@@ -155,11 +164,6 @@ def resolve_backend(cfg) -> str:
     if b is None:
         return get_backend()
     return _checked(b, "QuantConfig.backend")
-
-
-def interpret_default() -> bool:
-    """False only when REPRO_PALLAS_COMPILED=1 (compiled MXU path on TPU)."""
-    return os.environ.get("REPRO_PALLAS_COMPILED", "0") != "1"
 
 
 def window_veto() -> bool:
@@ -186,13 +190,15 @@ def _halve(x: int) -> int:
     return max(_LANE, _round_up(x // 2, _LANE))
 
 
-def qmatmul_blocks(m: int, n: int, k: int, *,
+def qmatmul_blocks(m: int, n: int, k: int, *, packed: bool = False,
                    budget: int = VMEM_BUDGET) -> tuple[int, int, int]:
     """(bm, bn, bk) for an (M,K) x (N,K)^T int8 matmul.
 
     Tile VMEM ~ bm*bk + bn*bk (int8 operands) + 8*bm*bn (int32 acc + f32
     out).  Prefer covering K in one step (single-shot accumulator, no
     revisits of the output tile), then grow bm/bn toward the MXU sweet spot.
+    ``packed`` (nibble weights) rounds bk to 256 so the kernel's bk/2-wide
+    nibble-plane tiles stay lane-aligned.
     """
     bk = min(_round_up(k, _LANE), 2048)
     bm = min(_round_up(m, _LANE), 256)
@@ -204,6 +210,8 @@ def qmatmul_blocks(m: int, n: int, k: int, *,
             bm = _halve(bm)
         else:
             bn = _halve(bn)
+    if packed:
+        bk = _round_up(bk, 2 * _LANE)
     return bm, bn, bk
 
 
@@ -334,11 +342,10 @@ def maybe_qlinear(x, p: dict, cfg):
         scale = (p["w_scale"] * xq.scale).astype(jnp.float32)
         row_scale = None
     bias = p.get("b")
-    bm, bn, bk = qmatmul_blocks(x2.shape[0], n, kdim)
+    bm, bn, bk = qmatmul_blocks(x2.shape[0], n, kdim, packed=packed)
     out = qmatmul(x2, w_q, scale,
                   None if bias is None else bias.astype(jnp.float32),
-                  row_scale, bm=bm, bn=bn, bk=bk, packed=packed,
-                  interpret=interpret_default())
+                  row_scale, bm=bm, bn=bn, bk=bk, packed=packed)
     return out.reshape(*x.shape[:-1], n).astype(x.dtype)
 
 
@@ -464,7 +471,7 @@ def _fused_call(q, k, v, spec, cfg):
     quantized_in = any(isinstance(x, quant.QTensor) for x in (q, k, v))
     if quantized_in:
         qq, kq, vq = (_as_q(x, cfg.a_bits) for x in (q, k, v))
-        sc = scale * LOG2E * qq.scale * kq.scale    # same assoc as XLA
+        sc = quant.pin(scale * LOG2E * qq.scale) * kq.scale   # as XLA's
         vs = vq.scale
         qf = qq.q.reshape(b, hkv, g, sq, d).reshape(b * hkv, g * sq, d)
         kf, vf = kq.q, vq.q
@@ -484,19 +491,20 @@ def _fused_call(q, k, v, spec, cfg):
                                   chunk=c)
         # One scale per bq-tile: tile i covers positions
         # [(i*bq) % sq, +bq) of group (i*bq) // sq — inside one chunk
-        # because bq divides c.
+        # because bq divides c.  The per-chunk product is formed before the
+        # gather, on the same operands as the XLA path's.
         nq = (g * sq) // bq
         tile_chunk = (np.arange(nq) * bq % sq) // c
-        qs_b = qsc.reshape(b, n_ch)
-        sc = scale * LOG2E * qs_b[:, tile_chunk] * ksc.reshape(b, 1)
+        sc = quant.pin(scale * LOG2E * qsc.reshape(b, n_ch)) \
+            * ksc.reshape(b, 1)
+        sc = sc[:, tile_chunk]
         sc = jnp.repeat(sc, hkv, axis=0)            # (b*hkv, nq)
         vs = jnp.repeat(vsc.reshape(b), hkv)        # (b*hkv,)
     kf = kf.reshape(b * hkv, sk, d)
     vf = vf.reshape(b * hkv, sk, d)
     out = int_attention_fused(qf, kf, vf, sc, vs,
                               attn_bits=cfg.attn_bits, causal=spec.causal,
-                              window=spec.window, bq=bq, bk=bk, sq_mod=sq,
-                              interpret=interpret_default())
+                              window=spec.window, bq=bq, bk=bk, sq_mod=sq)
     out = out.reshape(b, hkv, g, sq, d).reshape(b, hq, sq, d)
     return out.astype(out_dtype)
 
@@ -519,7 +527,7 @@ def _decode_call(q, k, v, spec, cfg, q_offset, k_positions):
     qq, kq, vq = (_as_q(x, cfg.a_bits) for x in (q, k, v))
     packed = _is_packed(kq)
     scale = spec.softmax_scale or (1.0 / d ** 0.5)
-    sc = scale * LOG2E * qq.scale * kq.scale    # same assoc as the XLA path
+    sc = quant.pin(scale * LOG2E * qq.scale) * kq.scale   # as XLA's
     qf = qq.q.reshape(b, hkv, g, d).reshape(b * hkv, g, d)
     kf = kq.q.reshape(b * hkv, span, -1)
     vf = vq.q.reshape(b * hkv, span, -1)
@@ -528,8 +536,7 @@ def _decode_call(q, k, v, spec, cfg, q_offset, k_positions):
                                jnp.asarray(k_positions, jnp.int32),
                                q_offset, attn_bits=cfg.attn_bits,
                                causal=spec.causal, window=spec.window,
-                               bk=bk, packed=packed,
-                               interpret=interpret_default())
+                               bk=bk, packed=packed)
     return out.reshape(b, hq, 1, d).astype(out_dtype)
 
 
@@ -561,7 +568,7 @@ def paged_query_grid(q, spec, cfg, k_scale=None):
     """
     qq, qscale = quantize_rows(q, cfg.a_bits)
     scale = spec.softmax_scale or (1.0 / q.shape[-1] ** 0.5)
-    sc = scale * LOG2E * qscale.astype(jnp.float32)
+    sc = quant.pin(scale * LOG2E * qscale.astype(jnp.float32))
     if k_scale is not None:
         sc = sc * jnp.asarray(k_scale, jnp.float32).reshape(-1)
     return qq, sc
@@ -642,6 +649,5 @@ def _paged_call(q, k_pages, v_pages, k_scale, v_scale, spec, cfg,
         qq.reshape(b, hkv, g, d), k_pages, v_pages, sc, vs,
         page_table, pos, k_page_scale=k_page_scale,
         v_page_scale=v_page_scale, attn_bits=cfg.attn_bits,
-        window=spec.window, packed=k_pages.dtype == jnp.uint8,
-        interpret=interpret_default())
+        window=spec.window, packed=k_pages.dtype == jnp.uint8)
     return out.reshape(b, hq, 1, d).astype(q.dtype)

@@ -69,8 +69,20 @@ sum_j (p_j - 128) v_j + 128 * sum_j v_j``, all in int32), closing the
 8-bit paper-parity gap without leaving the integer path.  int32 per-block
 PV accumulation is safe while ``attn_bits + 7 + log2(bk) + 1 <= 32``.
 
-``interpret=True`` (default) validates on CPU; set ``REPRO_PALLAS_COMPILED=1``
-(see kernels/dispatch.py) to run the compiled MXU path on TPU.
+TPU layout rules shape the operands.  Per-sequence, per-head and per-page
+scales are scalars read from SMEM (whole-array specs or the scalar-prefetch
+stream, all flattened to 1-D), never (1, 1) VMEM blocks.  Running carries
+are (rows, 1) columns.  Each kernel returns its unscaled PV accumulator and
+its Sigma column; the wrapper applies ``dattn = (2/qmax) / Sigma`` (and the
+v step) in XLA, so both backends divide with the same instruction
+sequence.  Nibble-packed KV tiles are split into low/high planes
+(:func:`~repro.kernels.qmatmul.nibble_planes`) and contracted against the
+even/odd query and output columns, which the wrapper de-interleaves and
+re-interleaves: the integer sums are exact, so this equals the unpacked
+contraction bit for bit.
+
+Kernels compile for the MXU on a TPU and run in interpret mode elsewhere
+(:func:`repro.kernels.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -82,15 +94,17 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.qmatmul import _unpack_nibbles
+from repro.core.softmax2 import exp2_shift, pow2, tree_sum
+from repro.kernels import interpret_mode
+from repro.kernels.qmatmul import nibble_planes
 
 NEG = -1e30
 MAX_PROB_BITS = 8
 
 
-def _exp2_shift(x):
-    f = jnp.floor(x)
-    return jnp.ldexp(1.0 + (x - f), f.astype(jnp.int32))
+def _smem():
+    """Whole-array operand in scalar memory (per-row / per-page scales)."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _mask(i, kblk, bq, bk, sq_mod, sk, causal, window):
@@ -113,8 +127,8 @@ def _mask(i, kblk, bq, bk, sq_mod, sk, causal, window):
 def _tile_logits(q_ref, k_ref, sc, valid):
     """Masked, clamped base-2 logits of one tile (int8 MXU contraction).
 
-    ``sc`` is this tile's scalar logit scale — per (head-fold, q-block)
-    since PR 4, so every bq-tile dequantizes on its own activation grid.
+    ``sc`` is this tile's scalar logit scale — per (head-fold, q-block),
+    so every bq-tile dequantizes on its own activation grid.
     """
     acc = jnp.dot(q_ref[0], k_ref[0].T, preferred_element_type=jnp.int32)
     x = acc.astype(jnp.float32) * sc
@@ -124,17 +138,18 @@ def _tile_logits(q_ref, k_ref, sc, valid):
 def _online_update(x, m_ref, qmax):
     """Advance running m, emit this tile's codes + rescale factor + e-sum.
 
-    8-bit grids (qmax = 255) store codes biased by -128 so they fit the
-    MXU's int8 operands; :func:`_pv_dot` adds the exact un-bias term.
+    ``m_ref`` is the (rows, 1) running-max column; ``r`` comes back in the
+    same shape.  8-bit grids (qmax = 255) store codes biased by -128 so they
+    fit the MXU's int8 operands; :func:`_pv_dot` adds the exact un-bias term.
     """
     m_old = m_ref[...]
-    m_new = jnp.maximum(m_old, jnp.floor(jnp.max(x, axis=-1)))
-    e = jnp.where(x <= -120.0, 0.0, _exp2_shift(x - m_new[:, None]))
+    m_new = jnp.maximum(m_old, jnp.floor(jnp.max(x, axis=-1, keepdims=True)))
+    e = jnp.where(x <= -120.0, 0.0, exp2_shift(x - m_new))
     p = jnp.clip(jnp.round(e * (qmax / 2.0)), 0, qmax)
     if qmax > 127:                   # biased uint8-as-int8 storage
         p = p - 128.0
     p_q = p.astype(jnp.int8)
-    r = jnp.exp2(m_old - m_new)      # exact: both integers (or -inf -> 0)
+    r = pow2(m_old - m_new)          # exact: both integers (NEG init -> 0)
     m_ref[...] = m_new
     return e, p_q, r
 
@@ -152,6 +167,42 @@ def _pv_dot(p_q, v, qmax):
     return pv
 
 
+def _kv_planes(tile, packed):
+    """A KV tile as stored -> its int8 column planes (two when packed)."""
+    return nibble_planes(tile) if packed else (tile,)
+
+
+def _planes_dot(q_planes, k_planes):
+    """sum_j q_j @ k_j^T over column planes (exact int32 partial sums)."""
+    acc = None
+    for q, k in zip(q_planes, k_planes):
+        part = jnp.dot(q, k.T, preferred_element_type=jnp.int32)
+        acc = part if acc is None else acc + part
+    return acc
+
+
+def _to_planes(x, npl):
+    """(..., rows, d) -> (..., npl, rows, d // npl): plane j = columns j::npl."""
+    *lead, rows, d = x.shape
+    return jnp.moveaxis(x.reshape(*lead, rows, d // npl, npl), -1, -3)
+
+
+def _from_planes(x):
+    """Inverse of :func:`_to_planes`."""
+    *lead, npl, rows, dp = x.shape
+    return jnp.moveaxis(x, -3, -1).reshape(*lead, rows, dp * npl)
+
+
+def _finish(acc, s, qmax, v_scale=None):
+    """Kernel epilogue, applied in XLA: ``acc * (dattn [* dv])``.
+
+    ``s`` is the Sigma column the kernel returned; ``v_scale`` broadcasts
+    against ``acc`` (None when the kernel already folded dv per block).
+    """
+    dattn = (2.0 / qmax) / jnp.maximum(s, 1e-30)
+    return acc * dattn if v_scale is None else acc * (dattn * v_scale)
+
+
 # ---------------------------------------------------------------------------
 # Live-block maps (bounded-key streaming)
 # ---------------------------------------------------------------------------
@@ -163,7 +214,8 @@ def _live_kblock_meta(nq, nk, bq, bk, sq_mod, sk, causal, window):
     repeated]``.  A tile is live iff any (q row, key) pair in it passes
     :func:`_mask`; repeating the last id means dead grid steps re-map the
     previous block, so Pallas issues no DMA for them.  Returns
-    ``(meta (nq, 1 + nt) int32, nt)`` with ``nt = max live tiles per row``.
+    ``(meta (nq * (1 + nt),) int32, nt)`` — rows flattened for SMEM — with
+    ``nt = max live tiles per row``.
     """
     q_pos = np.arange(nq * bq) % sq_mod
     lo = (np.maximum(q_pos - (window - 1), 0) if window is not None
@@ -183,7 +235,7 @@ def _live_kblock_meta(nq, nk, bq, bk, sq_mod, sk, causal, window):
             ids = np.array([0])
         meta[i, 1:1 + len(ids)] = ids
         meta[i, 1 + len(ids):] = ids[-1]
-    return jnp.asarray(meta), nt
+    return jnp.asarray(meta.reshape(-1)), nt
 
 
 def _decode_meta(k_positions, pos, nk, bk, causal, window):
@@ -211,14 +263,15 @@ def _paged_meta(page_table, pos, num_phys, page_size, window):
     """RUNTIME per-sequence page map for the paged decode kernel.
 
     Row ``b`` is ``[pos_b, n_live, physical page ids (P entries), logical
-    page ids (P entries)]``.  Live logical pages are the window-clipped
-    span ``[lo_b, pos_b // page_size]`` (logical page ``l`` holds positions
+    page ids (P entries)]``, rows flattened to one (B * (2 + 2P),) vector
+    for SMEM.  Live logical pages are the window-clipped span ``[lo_b,
+    pos_b // page_size]`` (logical page ``l`` holds positions
     ``l*page_size .. l*page_size + page_size - 1``); dead grid steps repeat
     the last live entry so Pallas issues no DMA for them.  ``pos_b < 0``
     marks an inactive row: zero live pages, every step dead.  An
     UNALLOCATED entry (< 0) inside the live span DMAs physical page 0 but
-    its logical id is emitted as -1, which fails the body's ``kp >= 0``
-    mask — the hole contributes e = 0 (bit-exact skip), matching the
+    its logical id is emitted as -1, which the body's liveness test
+    rejects — the hole contributes e = 0 (bit-exact skip), matching the
     oracle's ``kpos = -1`` for unallocated slots.
     """
     b, p = page_table.shape
@@ -232,16 +285,16 @@ def _paged_meta(page_table, pos, num_phys, page_size, window):
     phys = jnp.clip(raw, 0, num_phys - 1)
     logical = jnp.where(raw >= 0, logical, -1)
     return jnp.concatenate([pos[:, None], n_live[:, None], phys, logical],
-                           axis=1).astype(jnp.int32)
+                           axis=1).astype(jnp.int32).reshape(-1)
 
 
 # ---------------------------------------------------------------------------
 # Kernel bodies
 # ---------------------------------------------------------------------------
 
-def _stats_kernel(q_ref, k_ref, sc_ref, s_ref, mb_ref, sb_ref, *,
-                  nk, bq, bk, sq_mod, sk, causal, window, qmax):
-    i, kblk = pl.program_id(1), pl.program_id(2)
+def _stats_kernel(sc_ref, q_ref, k_ref, s_ref, mb_ref, sb_ref, *,
+                  nq, nk, bq, bk, sq_mod, sk, causal, window, qmax):
+    h, i, kblk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(kblk == 0)
     def _init():
@@ -255,19 +308,18 @@ def _stats_kernel(q_ref, k_ref, sc_ref, s_ref, mb_ref, sb_ref, *,
     # the MXU contraction.
     @pl.when(jnp.any(valid))
     def _compute():
-        x = _tile_logits(q_ref, k_ref, sc_ref[0, 0], valid)
+        x = _tile_logits(q_ref, k_ref, sc_ref[h * nq + i], valid)
         e, _, r = _online_update(x, mb_ref, qmax)
-        sb_ref[...] = sb_ref[...] * r + jnp.sum(e, axis=-1)
+        sb_ref[...] = sb_ref[...] * r + tree_sum(e)
 
     @pl.when(kblk == nk - 1)
     def _out():
-        s_ref[0, :] = jnp.maximum(sb_ref[...], 1e-30)
+        s_ref[0] = sb_ref[...]
 
 
-def _pv_kernel(q_ref, k_ref, v_ref, sc_ref, vs_ref, s_ref, o_ref,
-               mb_ref, acc_ref, *, nk, bq, bk, sq_mod, sk, causal, window,
-               qmax):
-    i, kblk = pl.program_id(1), pl.program_id(2)
+def _pv_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, mb_ref, acc_ref, *,
+               nq, nk, bq, bk, sq_mod, sk, causal, window, qmax):
+    h, i, kblk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(kblk == 0)
     def _init():
@@ -278,20 +330,19 @@ def _pv_kernel(q_ref, k_ref, v_ref, sc_ref, vs_ref, s_ref, o_ref,
 
     @pl.when(jnp.any(valid))
     def _compute():
-        x = _tile_logits(q_ref, k_ref, sc_ref[0, 0], valid)
+        x = _tile_logits(q_ref, k_ref, sc_ref[h * nq + i], valid)
         _, p_q, r = _online_update(x, mb_ref, qmax)
         pv = _pv_dot(p_q, v_ref[0], qmax)
-        acc_ref[...] = acc_ref[...] * r[:, None] + pv.astype(jnp.float32)
+        acc_ref[...] = acc_ref[...] * r + pv.astype(jnp.float32)
 
     @pl.when(kblk == nk - 1)
     def _out():
-        dattn = (2.0 / qmax) / s_ref[0, :][:, None]
-        o_ref[0] = acc_ref[...] * (dattn * vs_ref[0, 0])
+        o_ref[0] = acc_ref[...]
 
 
-def _fused_kernel(meta_ref, sc_ref, q_ref, k_ref, v_ref, vs_ref, o_ref,
-                  mb_ref, sb_ref, acc_ref, *, nt, bq, bk, sq_mod, sk, causal,
-                  window, qmax):
+def _fused_kernel(meta_ref, sc_ref, q_ref, k_ref, v_ref, o_ref, s_ref,
+                  mb_ref, sb_ref, acc_ref, *, nq, nt, bq, bk, sq_mod, sk,
+                  causal, window, qmax):
     h, i, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(t == 0)
@@ -303,31 +354,31 @@ def _fused_kernel(meta_ref, sc_ref, q_ref, k_ref, v_ref, vs_ref, o_ref,
     # The key tile in VMEM is meta[i, 1 + t], not t: dead tiles were never
     # DMA'd.  Guard on liveness so the repeated tail entries do not double
     # count their block.
-    kblk = meta_ref[i, 1 + t]
-    live = t < meta_ref[i, 0]
+    row = i * (1 + nt)
+    kblk = meta_ref[row + 1 + t]
+    live = t < meta_ref[row]
     valid = _mask(i, kblk, bq, bk, sq_mod, sk, causal, window)
 
     @pl.when(live & jnp.any(valid))
     def _compute():
         # Per-q-block activation scale, read straight from the prefetched
         # (h, nq) vector: every bq-tile dequantizes on its own grid.
-        x = _tile_logits(q_ref, k_ref, sc_ref[h, i], valid)
+        x = _tile_logits(q_ref, k_ref, sc_ref[h * nq + i], valid)
         e, p_q, r = _online_update(x, mb_ref, qmax)
         pv = _pv_dot(p_q, v_ref[0], qmax)
-        sb_ref[...] = sb_ref[...] * r + jnp.sum(e, axis=-1)
-        acc_ref[...] = acc_ref[...] * r[:, None] + pv.astype(jnp.float32)
+        sb_ref[...] = sb_ref[...] * r + tree_sum(e)
+        acc_ref[...] = acc_ref[...] * r + pv.astype(jnp.float32)
 
     @pl.when(t == nt - 1)
     def _out():
-        s = jnp.maximum(sb_ref[...], 1e-30)[:, None]
-        dattn = (2.0 / qmax) / s
-        o_ref[0] = acc_ref[...] * (dattn * vs_ref[0, 0])
+        o_ref[0] = acc_ref[...]
+        s_ref[0] = sb_ref[...]
 
 
-def _decode_kernel(meta_ref, q_ref, k_ref, v_ref, kp_ref, sc_ref, vs_ref,
-                   o_ref, mb_ref, sb_ref, acc_ref, *, nt, causal, window,
+def _decode_kernel(meta_ref, sc_ref, q_ref, k_ref, v_ref, kp_ref, o_ref,
+                   s_ref, mb_ref, sb_ref, acc_ref, *, nt, causal, window,
                    qmax, packed):
-    t = pl.program_id(1)
+    h, t = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
@@ -337,7 +388,7 @@ def _decode_kernel(meta_ref, q_ref, k_ref, v_ref, kp_ref, sc_ref, vs_ref,
 
     pos = meta_ref[0]
     live = t < meta_ref[1]
-    kp = kp_ref[0, :]                    # ring positions of this key tile
+    kp = kp_ref[...]                     # (1, bk) ring positions of the tile
     valid = kp >= 0                      # negative = unwritten slot
     if causal:
         valid &= kp <= pos
@@ -346,25 +397,28 @@ def _decode_kernel(meta_ref, q_ref, k_ref, v_ref, kp_ref, sc_ref, vs_ref,
 
     @pl.when(live & jnp.any(valid))
     def _compute():
-        k = _unpack_nibbles(k_ref[0]) if packed else k_ref[0]
-        v = _unpack_nibbles(v_ref[0]) if packed else v_ref[0]
-        acc = jnp.dot(q_ref[0], k.T, preferred_element_type=jnp.int32)
-        x = acc.astype(jnp.float32) * sc_ref[0, 0]
-        x = jnp.maximum(jnp.where(valid[None, :], x, NEG), -120.0)
+        vpl = _kv_planes(v_ref[0], packed)
+        acc = _planes_dot([q_ref[0, j] for j in range(len(vpl))],
+                          _kv_planes(k_ref[0], packed))
+        x = acc.astype(jnp.float32) * sc_ref[h]
+        x = jnp.maximum(jnp.where(valid, x, NEG), -120.0)
         e, p_q, r = _online_update(x, mb_ref, qmax)
-        pv = _pv_dot(p_q, v, qmax)
-        sb_ref[...] = sb_ref[...] * r + jnp.sum(e, axis=-1)
-        acc_ref[...] = acc_ref[...] * r[:, None] + pv.astype(jnp.float32)
+        sb_ref[...] = sb_ref[...] * r + tree_sum(e)
+        for j, v in enumerate(vpl):
+            acc_ref[j] = acc_ref[j] * r + \
+                _pv_dot(p_q, v, qmax).astype(jnp.float32)
 
     @pl.when(t == nt - 1)
     def _out():
-        s = jnp.maximum(sb_ref[...], 1e-30)[:, None]
-        o_ref[0] = acc_ref[...] * ((2.0 / qmax) / s * vs_ref[0, 0])
+        o_ref[0] = acc_ref[...]
+        s_ref[0] = sb_ref[...]
 
 
-def _paged_decode_kernel(meta_ref, q_ref, k_ref, v_ref, sc_ref, vs_ref,
-                         kps_ref, vps_ref, o_ref, mb_ref, sb_ref, acc_ref, *,
-                         nt, page_size, window, qmax, packed, page_scaled):
+def _paged_decode_kernel(meta_ref, q_ref, k_ref, v_ref, sc_ref, *refs, nt,
+                         page_size, window, qmax, packed, page_scaled):
+    if page_scaled:
+        vs_ref, kps_ref, vps_ref, *refs = refs
+    o_ref, s_ref, mb_ref, sb_ref, acc_ref = refs
     b, t = pl.program_id(0), pl.program_id(2)
 
     @pl.when(t == 0)
@@ -373,49 +427,48 @@ def _paged_decode_kernel(meta_ref, q_ref, k_ref, v_ref, sc_ref, vs_ref,
         sb_ref[...] = jnp.zeros_like(sb_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos = meta_ref[b, 0]
-    live = t < meta_ref[b, 1]
+    row = b * (2 + 2 * nt)
+    pos = meta_ref[row]
+    live = t < meta_ref[row + 1]
+    phys = meta_ref[row + 2 + t]
     # Key positions are implied by the logical page id: no per-slot position
     # map is stored (unlike the ring kernel) — page r of logical page l is
-    # absolute position l*page_size + r.
-    logical = meta_ref[b, 2 + nt + t]
-    kp = logical * page_size + \
-        jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-    # kp >= 0 rejects unallocated pages inside the span (logical = -1).
+    # absolute position l*page_size + r.  logical = -1 marks an unallocated
+    # page inside the span.
+    first = meta_ref[row + 2 + nt + t] * page_size
+    kp = first + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
     valid = (kp >= 0) & (kp <= pos)
+    page_live = (first >= 0) & (first <= pos)
     if window is not None:
         valid &= kp > pos - window
+        page_live &= first + page_size - 1 > pos - window
 
-    @pl.when(live & jnp.any(valid))
+    @pl.when(live & page_live)
     def _compute():
-        k = _unpack_nibbles(k_ref[0, 0]) if packed else k_ref[0, 0]
-        v = _unpack_nibbles(v_ref[0, 0]) if packed else v_ref[0, 0]
-        acc = jnp.dot(q_ref[0, 0], k.T, preferred_element_type=jnp.int32)
+        vpl = _kv_planes(v_ref[0, 0], packed)
+        acc = _planes_dot([q_ref[0, 0, j] for j in range(len(vpl))],
+                          _kv_planes(k_ref[0, 0], packed))
         # page_scaled: this page's codes dequantize on the grid they were
         # PREFILLED with (prefix-sharing: the prefix owner's scale, read
         # per physical page through the meta's phys-id stream), so shared
         # pages never re-scale to the reading tenant's grid.
         if page_scaled:
-            x = acc.astype(jnp.float32) * (sc_ref[0, 0] * kps_ref[0, 0])
+            x = acc.astype(jnp.float32) * (sc_ref[b] * kps_ref[phys])
         else:
-            x = acc.astype(jnp.float32) * sc_ref[0, 0]
+            x = acc.astype(jnp.float32) * sc_ref[b]
         x = jnp.maximum(jnp.where(valid, x, NEG), -120.0)
         e, p_q, r = _online_update(x, mb_ref, qmax)
-        pv = _pv_dot(p_q, v, qmax)
-        sb_ref[...] = sb_ref[...] * r + jnp.sum(e, axis=-1)
-        if page_scaled:
-            pv_f = pv.astype(jnp.float32) * (vs_ref[0, 0] * vps_ref[0, 0])
-        else:
-            pv_f = pv.astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * r[:, None] + pv_f
+        sb_ref[...] = sb_ref[...] * r + tree_sum(e)
+        for j, v in enumerate(vpl):
+            pv = _pv_dot(p_q, v, qmax).astype(jnp.float32)
+            if page_scaled:
+                pv = pv * (vs_ref[b] * vps_ref[phys])
+            acc_ref[j] = acc_ref[j] * r + pv
 
     @pl.when(t == nt - 1)
     def _out():
-        s = jnp.maximum(sb_ref[...], 1e-30)[:, None]
-        if page_scaled:                   # dv folded per block above
-            o_ref[0, 0] = acc_ref[...] * ((2.0 / qmax) / s)
-        else:
-            o_ref[0, 0] = acc_ref[...] * ((2.0 / qmax) / s * vs_ref[0, 0])
+        o_ref[0, 0] = acc_ref[...]
+        s_ref[0, 0] = sb_ref[...]
 
 
 # ---------------------------------------------------------------------------
@@ -435,39 +488,28 @@ def _prep(q_q, k_q, v_q, bq, bk):
 
 
 def _grid_scales(sc, v_scale, h, nq):
-    """Broadcast kernel epilogue scales to their grid shapes.
+    """Broadcast the epilogue scales to their grid shapes.
 
     ``sc``: scalar (one grid for the whole call), (nq,) per-q-block vector,
     or (h, nq) per (head-fold, q-block) — the finest granularity: dispatch
     folds batch into the head axis and XLA-chunk-sized row groups into q
     blocks, so per-sequence-per-chunk activation grids land here.
-    ``v_scale``: scalar or (h,) per-head-fold.  Returns ((h, nq) f32,
-    (h, 1) f32).
+    ``v_scale``: scalar or (h,) per-head-fold.  Returns (sc flattened to
+    (h * nq,) f32 for SMEM, v_scale as (h, 1, 1) f32).
     """
     sc = jnp.asarray(sc, jnp.float32)
     if sc.ndim == 1:
         sc = sc[None, :]
-    sc = jnp.broadcast_to(sc, (h, nq))
-    vs = jnp.broadcast_to(jnp.asarray(v_scale, jnp.float32).reshape(-1, 1),
-                          (h, 1))
+    sc = jnp.broadcast_to(sc, (h, nq)).reshape(-1)
+    vs = jnp.broadcast_to(jnp.asarray(v_scale, jnp.float32).reshape(-1),
+                          (h,)).reshape(h, 1, 1)
     return sc, vs
-
-
-def _specs(bq, bk, d):
-    return dict(
-        qspec=pl.BlockSpec((1, bq, d), lambda h, i, k: (h, i, 0)),
-        kspec=pl.BlockSpec((1, bk, d), lambda h, i, k: (h, k, 0)),
-        # per (head-fold, q-block) logit scale / per-head-fold v scale
-        scspec=pl.BlockSpec((1, 1), lambda h, i, k: (h, i)),
-        vsspec=pl.BlockSpec((1, 1), lambda h, i, k: (h, 0)),
-        rowspec=pl.BlockSpec((1, bq), lambda h, i, k: (h, i)),
-    )
 
 
 @functools.partial(jax.jit, static_argnames=(
     "attn_bits", "causal", "window", "bq", "bk", "sq_mod", "interpret"))
 def int_attention(q_q, k_q, v_q, sc, v_scale, *, attn_bits=7, causal=True,
-                  window=None, bq=128, bk=128, sq_mod=None, interpret=True):
+                  window=None, bq=128, bk=128, sq_mod=None, interpret=None):
     """TWO-PASS integer attention over int8 operands (baseline design).
 
     q_q: (H, Sq, D) int8 (GQA pre-folded: G query groups stacked along Sq,
@@ -475,7 +517,7 @@ def int_attention(q_q, k_q, v_q, sc, v_scale, *, attn_bits=7, causal=True,
     (H, Sk, D) int8.  ``sc`` = softmax_scale * dq * dk * log2(e) — scalar,
     (nq,) per-q-block, or (H, nq) per (head-fold, q-block) f32 (per-block
     activation grids); ``v_scale`` = dv (scalar or (H,)).  Returns
-    (H, Sq, D) f32.
+    (H, Sq, D) f32.  ``interpret``: see :func:`repro.kernels.interpret_mode`.
 
     Pass 1 sweeps K once for Sigma; pass 2 re-sweeps K, recomputing QK^T
     and the running-m code sequence (identical to the fused kernel's), and
@@ -488,40 +530,41 @@ def int_attention(q_q, k_q, v_q, sc, v_scale, *, attn_bits=7, causal=True,
     sk = k_q.shape[1]
     qmax = float((1 << attn_bits) - 1)
     q_q, k_q, v_q, nq, nk = _prep(q_q, k_q, v_q, bq, bk)
-    sc2, vs2 = _grid_scales(sc, v_scale, h, nq)
-    sp = _specs(bq, bk, d)
-    kw = dict(nk=nk, bq=bq, bk=bk, sq_mod=sq_mod or sq, sk=sk,
+    sc2, vs = _grid_scales(sc, v_scale, h, nq)
+    kw = dict(nq=nq, nk=nk, bq=bq, bk=bk, sq_mod=sq_mod or sq, sk=sk,
               causal=causal, window=window, qmax=qmax)
+    qspec = pl.BlockSpec((1, bq, d), lambda h, i, k: (h, i, 0))
+    kspec = pl.BlockSpec((1, bk, d), lambda h, i, k: (h, k, 0))
+    interpret = interpret_mode(interpret)
 
     s = pl.pallas_call(
         functools.partial(_stats_kernel, **kw),
         grid=(h, nq, nk),
-        in_specs=[sp["qspec"], sp["kspec"], sp["scspec"]],
-        out_specs=sp["rowspec"],
-        out_shape=jax.ShapeDtypeStruct((h, nq * bq), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq,), jnp.float32)] * 2,
+        in_specs=[_smem(), qspec, kspec],
+        out_specs=pl.BlockSpec((1, bq, 1), lambda h, i, k: (h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((h, nq * bq, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32)] * 2,
         interpret=interpret,
-    )(q_q, k_q, sc2)
+    )(sc2, q_q, k_q)
 
-    out = pl.pallas_call(
+    acc = pl.pallas_call(
         functools.partial(_pv_kernel, **kw),
         grid=(h, nq, nk),
-        in_specs=[sp["qspec"], sp["kspec"], sp["kspec"], sp["scspec"],
-                  sp["vsspec"], sp["rowspec"]],
-        out_specs=sp["qspec"],
+        in_specs=[_smem(), qspec, kspec, kspec],
+        out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((h, nq * bq, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq,), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-    )(q_q, k_q, v_q, sc2, vs2, s)
-    return out[:, :sq]
+    )(sc2, q_q, k_q, v_q)
+    return _finish(acc, s, qmax, vs)[:, :sq]
 
 
 @functools.partial(jax.jit, static_argnames=(
     "attn_bits", "causal", "window", "bq", "bk", "sq_mod", "interpret"))
 def int_attention_fused(q_q, k_q, v_q, sc, v_scale, *, attn_bits=7,
                         causal=True, window=None, bq=128, bk=128,
-                        sq_mod=None, interpret=True):
+                        sq_mod=None, interpret=None):
     """SINGLE-PASS fused integer attention (the prefill serving kernel).
 
     Same contract as :func:`int_attention`.  One sweep over K/V per query
@@ -537,7 +580,7 @@ def int_attention_fused(q_q, k_q, v_q, sc, v_scale, *, attn_bits=7,
 
     Per-query-block activation scales: ``sc`` broadcast to (H, nq) rides
     the scalar-prefetch stream next to the block map, so each bq-tile's
-    epilogue dequantizes with its own scale — dispatch threads per-sequence
+    logits dequantize with their own scale — dispatch threads per-sequence
     per-XLA-chunk q grids through here, closing the granularity gap with
     the chunked XLA path at Sq > q_chunk.
     """
@@ -547,53 +590,57 @@ def int_attention_fused(q_q, k_q, v_q, sc, v_scale, *, attn_bits=7,
     sk = k_q.shape[1]
     qmax = float((1 << attn_bits) - 1)
     q_q, k_q, v_q, nq, nk = _prep(q_q, k_q, v_q, bq, bk)
-    sc2, vs2 = _grid_scales(sc, v_scale, h, nq)
+    sc2, vs = _grid_scales(sc, v_scale, h, nq)
     meta, nt = _live_kblock_meta(nq, nk, bq, bk, sq_mod or sq, sk, causal,
                                  window)
+    w = 1 + nt                                # meta row width
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(h, nq, nt),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda h, i, t, m, s: (h, i, 0)),
             pl.BlockSpec((1, bk, d),
-                         lambda h, i, t, m, s: (h, m[i, 1 + t], 0)),
+                         lambda h, i, t, m, s: (h, m[i * w + 1 + t], 0)),
             pl.BlockSpec((1, bk, d),
-                         lambda h, i, t, m, s: (h, m[i, 1 + t], 0)),
-            pl.BlockSpec((1, 1), lambda h, i, t, m, s: (h, 0)),
+                         lambda h, i, t, m, s: (h, m[i * w + 1 + t], 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda h, i, t, m, s: (h, i, 0)),
-        scratch_shapes=[pltpu.VMEM((bq,), jnp.float32),
-                        pltpu.VMEM((bq,), jnp.float32),
+        out_specs=[
+            pl.BlockSpec((1, bq, d), lambda h, i, t, m, s: (h, i, 0)),
+            pl.BlockSpec((1, bq, 1), lambda h, i, t, m, s: (h, i, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
     )
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, nt=nt, bq=bq, bk=bk,
+    acc, s = pl.pallas_call(
+        functools.partial(_fused_kernel, nq=nq, nt=nt, bq=bq, bk=bk,
                           sq_mod=sq_mod or sq, sk=sk, causal=causal,
                           window=window, qmax=qmax),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((h, nq * bq, d), jnp.float32),
-        interpret=interpret,
-    )(meta, sc2, q_q, k_q, v_q, vs2)
-    return out[:, :sq]
+        out_shape=[jax.ShapeDtypeStruct((h, nq * bq, d), jnp.float32),
+                   jax.ShapeDtypeStruct((h, nq * bq, 1), jnp.float32)],
+        interpret=interpret_mode(interpret),
+    )(meta, sc2, q_q, k_q, v_q)
+    return _finish(acc, s, qmax, vs)[:, :sq]
 
 
 @functools.partial(jax.jit, static_argnames=(
     "attn_bits", "causal", "window", "bk", "packed", "interpret"))
 def int_decode_attention(q_q, k_q, v_q, sc, v_scale, k_positions, pos, *,
                          attn_bits=7, causal=True, window=None, bk=128,
-                         packed=False, interpret=True):
+                         packed=False, interpret=None):
     """Single-query integer decode attention over a KV ring cache, in place.
 
     q_q: (H, G, D) int8 — the G GQA query groups of one decode step as MXU
     rows (all share query position ``pos``).  k_q, v_q: the ring cache as
     stored — (H, span, D) int8, or (H, span, D//2) uint8 nibbles with
-    ``packed=True`` (unpacked on the VPU per tile; HBM reads stay halved).
-    ``k_positions``: (span,) int32, ring slot j's absolute position
-    (negative = unwritten slot, masked).  ``pos``: scalar int32 query
-    position (may be traced).  ``sc`` = softmax_scale * dq * dk * log2(e),
-    scalar or (H,) per head-fold row (batch rows folded into H quantize
-    their single query per sequence); ``v_scale`` = dv (scalar or (H,)).
-    Returns (H, G, D) f32.
+    ``packed=True`` (split into nibble planes on the VPU per tile; HBM
+    reads stay halved).  ``k_positions``: (span,) int32, ring slot j's
+    absolute position (negative = unwritten slot, masked).  ``pos``: scalar
+    int32 query position (may be traced).  ``sc`` = softmax_scale * dq * dk
+    * log2(e), scalar or (H,) per head-fold row (batch rows folded into H
+    quantize their single query per sequence); ``v_scale`` = dv (scalar or
+    (H,)).  Returns (H, G, D) f32.
 
     Bounded-key streaming: a runtime block map (:func:`_decode_meta`,
     scalar-prefetched so index maps see it) DMAs only ring blocks holding a
@@ -620,40 +667,42 @@ def int_decode_attention(q_q, k_q, v_q, sc, v_scale, k_positions, pos, *,
     if pg:
         q_q = jnp.pad(q_q, ((0, 0), (0, pg), (0, 0)))
     gq = g + pg
+    npl = 2 if packed else 1            # column planes per KV tile
+    dp = d // npl
     k_positions = k_positions.astype(jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
     meta = _decode_meta(k_positions, pos, nk, bk, causal, window)
     kp2 = k_positions.reshape(1, nk * bk)
-    sc2 = jnp.broadcast_to(
-        jnp.asarray(sc, jnp.float32).reshape(-1, 1), (h, 1))
-    vs2 = jnp.broadcast_to(
-        jnp.asarray(v_scale, jnp.float32).reshape(-1, 1), (h, 1))
-    dk = k_q.shape[-1]                  # d, or d//2 when nibble-packed
+    sc2 = jnp.broadcast_to(jnp.asarray(sc, jnp.float32).reshape(-1), (h,))
+    vs = jnp.broadcast_to(jnp.asarray(v_scale, jnp.float32).reshape(-1),
+                          (h,)).reshape(h, 1, 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(h, nk),
         in_specs=[
-            pl.BlockSpec((1, gq, d), lambda h, t, m: (h, 0, 0)),
-            pl.BlockSpec((1, bk, dk), lambda h, t, m: (h, m[2 + t], 0)),
-            pl.BlockSpec((1, bk, dk), lambda h, t, m: (h, m[2 + t], 0)),
-            pl.BlockSpec((1, bk), lambda h, t, m: (0, m[2 + t])),
-            pl.BlockSpec((1, 1), lambda h, t, m: (h, 0)),
-            pl.BlockSpec((1, 1), lambda h, t, m: (h, 0)),
+            pl.BlockSpec((1, npl, gq, dp), lambda h, t, m, s: (h, 0, 0, 0)),
+            pl.BlockSpec((1, bk, dp), lambda h, t, m, s: (h, m[2 + t], 0)),
+            pl.BlockSpec((1, bk, dp), lambda h, t, m, s: (h, m[2 + t], 0)),
+            pl.BlockSpec((1, bk), lambda h, t, m, s: (0, m[2 + t])),
         ],
-        out_specs=pl.BlockSpec((1, gq, d), lambda h, t, m: (h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((gq,), jnp.float32),
-                        pltpu.VMEM((gq,), jnp.float32),
-                        pltpu.VMEM((gq, d), jnp.float32)],
+        out_specs=[
+            pl.BlockSpec((1, npl, gq, dp), lambda h, t, m, s: (h, 0, 0, 0)),
+            pl.BlockSpec((1, gq, 1), lambda h, t, m, s: (h, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((gq, 1), jnp.float32),
+                        pltpu.VMEM((gq, 1), jnp.float32),
+                        pltpu.VMEM((npl, gq, dp), jnp.float32)],
     )
-    out = pl.pallas_call(
+    acc, s = pl.pallas_call(
         functools.partial(_decode_kernel, nt=nk, causal=causal,
                           window=window, qmax=qmax, packed=packed),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((h, gq, d), jnp.float32),
-        interpret=interpret,
-    )(meta, q_q, k_q, v_q, kp2, sc2, vs2)
-    return out[:, :g]
+        out_shape=[jax.ShapeDtypeStruct((h, npl, gq, dp), jnp.float32),
+                   jax.ShapeDtypeStruct((h, gq, 1), jnp.float32)],
+        interpret=interpret_mode(interpret),
+    )(meta, sc2, _to_planes(q_q, npl), k_q, v_q, kp2)
+    return _finish(_from_planes(acc), s, qmax, vs)[:, :g]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -661,7 +710,7 @@ def int_decode_attention(q_q, k_q, v_q, sc, v_scale, k_positions, pos, *,
 def int_paged_decode_attention(q_q, k_pages, v_pages, sc, v_scale,
                                page_table, pos, *, k_page_scale=None,
                                v_page_scale=None, attn_bits=7, window=None,
-                               packed=False, interpret=True):
+                               packed=False, interpret=None):
     """Single-query integer decode attention over a PAGED KV cache, in place.
 
     q_q: (B, Hkv, G, D) int8 — one decode step per sequence, the G GQA
@@ -685,7 +734,8 @@ def int_paged_decode_attention(q_q, k_pages, v_pages, sc, v_scale,
     from a prefix owner therefore keep the scales they were prefilled
     with, and a tenant's own activation grid never re-scales another's
     codes.  Both vectors must be given together; ``None`` keeps the
-    per-sequence contract above bit-for-bit.
+    per-sequence contract above bit-for-bit.  All scales live in SMEM
+    whole, so the pool size bounds them (one f32 per physical page each).
 
     This is :func:`int_decode_attention` with the runtime live-block map
     made per-sequence: grid step t of row b DMAs physical page
@@ -708,55 +758,60 @@ def int_paged_decode_attention(q_q, k_pages, v_pages, sc, v_scale,
         assert dk == d, (q_q.shape, k_pages.shape)
     qmax = float((1 << attn_bits) - 1)
     nt = page_table.shape[1]            # grid steps = max logical pages
+    w = 2 + 2 * nt                      # meta row width
     pg = (-g) % 8                       # f32 sublane alignment for scratch
     if pg:
         q_q = jnp.pad(q_q, ((0, 0), (0, 0), (0, pg), (0, 0)))
     gq = g + pg
+    npl = 2 if packed else 1            # column planes per KV tile
     pos = jnp.asarray(pos, jnp.int32).reshape(b)
     meta = _paged_meta(jnp.asarray(page_table, jnp.int32), pos, num_phys,
                        page_size, window)
-    sc2 = jnp.broadcast_to(jnp.asarray(sc, jnp.float32).reshape(-1, 1),
-                           (b, 1))
-    vs2 = jnp.broadcast_to(jnp.asarray(v_scale, jnp.float32).reshape(-1, 1),
-                           (b, 1))
+    sc2 = jnp.broadcast_to(jnp.asarray(sc, jnp.float32).reshape(-1), (b,))
+    vs = jnp.broadcast_to(jnp.asarray(v_scale, jnp.float32).reshape(-1),
+                          (b,))
     page_scaled = k_page_scale is not None
     assert page_scaled == (v_page_scale is not None), \
         "k_page_scale and v_page_scale must be given together"
+    scales = [sc2]
     if page_scaled:
-        kps2 = jnp.asarray(k_page_scale, jnp.float32).reshape(num_phys, 1)
-        vps2 = jnp.asarray(v_page_scale, jnp.float32).reshape(num_phys, 1)
-    else:                 # dead operands; the kernel never reads them
-        kps2 = vps2 = jnp.ones((num_phys, 1), jnp.float32)
+        # per-PHYSICAL-page k/v dequant steps, indexed in-kernel through
+        # the same phys-id stream as the page pools themselves
+        scales += [vs,
+                   jnp.asarray(k_page_scale, jnp.float32).reshape(num_phys),
+                   jnp.asarray(v_page_scale, jnp.float32).reshape(num_phys)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, hkv, nt),
         in_specs=[
-            pl.BlockSpec((1, 1, gq, d), lambda b, h, t, m: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, npl, gq, dk),
+                         lambda b, h, t, m: (b, h, 0, 0, 0)),
             pl.BlockSpec((1, 1, page_size, dk),
-                         lambda b, h, t, m: (m[b, 2 + t], h, 0, 0)),
+                         lambda b, h, t, m: (m[b * w + 2 + t], h, 0, 0)),
             pl.BlockSpec((1, 1, page_size, dk),
-                         lambda b, h, t, m: (m[b, 2 + t], h, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, t, m: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, t, m: (b, 0)),
-            # per-PHYSICAL-page k/v dequant steps ride the same phys-id
-            # stream as the page pools themselves
-            pl.BlockSpec((1, 1), lambda b, h, t, m: (m[b, 2 + t], 0)),
-            pl.BlockSpec((1, 1), lambda b, h, t, m: (m[b, 2 + t], 0)),
+                         lambda b, h, t, m: (m[b * w + 2 + t], h, 0, 0)),
+        ] + [_smem()] * len(scales),
+        out_specs=[
+            pl.BlockSpec((1, 1, npl, gq, dk),
+                         lambda b, h, t, m: (b, h, 0, 0, 0)),
+            pl.BlockSpec((1, 1, gq, 1), lambda b, h, t, m: (b, h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, gq, d), lambda b, h, t, m: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((gq,), jnp.float32),
-                        pltpu.VMEM((gq,), jnp.float32),
-                        pltpu.VMEM((gq, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((gq, 1), jnp.float32),
+                        pltpu.VMEM((gq, 1), jnp.float32),
+                        pltpu.VMEM((npl, gq, dk), jnp.float32)],
     )
-    out = pl.pallas_call(
+    acc, s = pl.pallas_call(
         functools.partial(_paged_decode_kernel, nt=nt, page_size=page_size,
                           window=window, qmax=qmax, packed=packed,
                           page_scaled=page_scaled),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, gq, d), jnp.float32),
-        interpret=interpret,
-    )(meta, q_q, k_pages, v_pages, sc2, vs2, kps2, vps2)
+        out_shape=[jax.ShapeDtypeStruct((b, hkv, npl, gq, dk), jnp.float32),
+                   jax.ShapeDtypeStruct((b, hkv, gq, 1), jnp.float32)],
+        interpret=interpret_mode(interpret),
+    )(meta, _to_planes(q_q, npl), k_pages, v_pages, *scales)
+    out = _finish(_from_planes(acc), s, qmax,
+                  None if page_scaled else vs.reshape(b, 1, 1, 1))
     return out[:, :, :g]
 
 

@@ -1,11 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True so the kernels validate on CPU (this
-container); on TPU pass ``interpret=False`` (or set REPRO_PALLAS_COMPILED=1)
-to run the compiled MXU path.  Model graphs normally reach the kernels
-through :mod:`repro.kernels.dispatch` (backend selection + shape policy);
-these wrappers are the direct, QTensor-typed entry points for tests and
-benchmarks.
+The kernels compile for the MXU on a TPU and run in interpret mode
+elsewhere (:func:`repro.kernels.interpret_mode`).  Model graphs normally
+reach the kernels through :mod:`repro.kernels.dispatch` (backend selection
++ shape policy); these wrappers are the direct, QTensor-typed entry points
+for tests and benchmarks.
 """
 from __future__ import annotations
 
@@ -14,7 +13,6 @@ import jax.numpy as jnp
 from repro.core.quant import QTensor
 from repro.core.integerize import QLinearParams
 from repro.core.softmax2 import LOG2E
-from repro.kernels.dispatch import interpret_default
 from repro.kernels.int_attention import int_attention, int_attention_fused
 from repro.kernels.pq_layernorm import pq_layernorm
 from repro.kernels.qmatmul import qmatmul
@@ -24,7 +22,6 @@ def qlinear_op(x: QTensor, p: QLinearParams, **kw):
     """Kernel-backed version of core.integerize.int_linear (2D inputs)."""
     scale = (p.w_scale * x.scale).astype(jnp.float32)
     bias = None if p.bias is None else p.bias.astype(jnp.float32)
-    kw.setdefault("interpret", interpret_default())
     return qmatmul(x.q, p.w_q, scale, bias, **kw)
 
 
@@ -37,12 +34,10 @@ def int_attention_op(q: QTensor, k: QTensor, v: QTensor, *, softmax_scale,
     the two-pass baseline.  Identical outputs, 2/3 the MXU MACs.
     """
     sc = softmax_scale * q.scale * k.scale * LOG2E
-    kw.setdefault("interpret", interpret_default())
     kern = int_attention_fused if fused else int_attention
     return kern(q.q, k.q, v.q, sc, v.scale, attn_bits=attn_bits,
                 causal=causal, window=window, **kw)
 
 
 def pq_layernorm_op(x, gamma, beta, delta, **kw):
-    kw.setdefault("interpret", interpret_default())
     return pq_layernorm(x, gamma, beta, delta, **kw)

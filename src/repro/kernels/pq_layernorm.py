@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _pqln_kernel(x_ref, g_ref, b_ref, d_ref, o_ref, *, eps, qmin, qmax,
                  rms_only):
@@ -33,8 +35,11 @@ def _pqln_kernel(x_ref, g_ref, b_ref, d_ref, o_ref, *, eps, qmin, qmax,
 @functools.partial(jax.jit, static_argnames=("bits", "eps", "rms_only", "br",
                                              "interpret"))
 def pq_layernorm(x, gamma, beta, delta, *, bits=8, eps=1e-6, rms_only=False,
-                 br=256, interpret=True):
-    """(rows, d) float -> (rows, d) int8 codes on the signed b-bit grid."""
+                 br=256, interpret=None):
+    """(rows, d) float -> (rows, d) int8 codes on the signed b-bit grid.
+
+    ``interpret``: see :func:`repro.kernels.interpret_mode`.
+    """
     rows, d = x.shape
     qmin, qmax = -(1 << (bits - 1)), (1 << (bits - 1)) - 1
     pr = (-rows) % br
@@ -57,6 +62,6 @@ def pq_layernorm(x, gamma, beta, delta, *, bits=8, eps=1e-6, rms_only=False,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pr, d), jnp.int8),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, g2, b2, d2)
     return out[:rows]

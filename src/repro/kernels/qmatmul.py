@@ -7,9 +7,14 @@ the dequantization is a per-output-channel epilogue applied to the int32
 accumulator tile while it is still in VMEM — the kernel-level realization of
 "delay dequantization until after the matrix operation".
 
-A packed variant stores W as 2x4-bit nibbles per byte in HBM and unpacks in
-VMEM, halving weight bandwidth (the TPU analogue of the paper's low-bit
-storage benefit).
+A packed variant stores W as 2x4-bit nibbles per byte in HBM, halving
+weight bandwidth (the TPU analogue of the paper's low-bit storage benefit).
+Byte j of a packed row holds logical columns 2j (low nibble) and 2j+1 (high
+nibble).  The kernel never re-interleaves them: it splits each weight tile
+into its low and high nibble planes (:func:`nibble_planes`) and contracts
+them against the even and odd columns of X, which the wrapper
+de-interleaves once.  Integer partial sums are exact, so the result equals
+the unpacked contraction bit for bit.
 
 Block sizes default to (128, 128, 512): MXU-aligned (multiples of 128 in
 lane dims) and VMEM-light (x: 64KB, w: 64KB int8, acc: 64KB int32).  The
@@ -26,46 +31,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _qmatmul_kernel(x_ref, w_ref, scale_ref, bias_ref, rs_ref, o_ref,
-                    acc_ref, *, nk: int, out_dtype):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...].T,
-                            preferred_element_type=jnp.int32)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        acc = acc_ref[...].astype(jnp.float32)
-        out = acc * (scale_ref[0, :][None, :] * rs_ref[:, 0][:, None]) \
-            + bias_ref[0, :][None, :]
-        o_ref[...] = out.astype(out_dtype)
+from repro.kernels import interpret_mode
 
 
-def _unpack_nibbles(packed):
-    """(bn, bk//2) uint8 -> (bn, bk) int8, low nibble first."""
-    lo = (packed & 0xF).astype(jnp.int8)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int8)
+def nibble_planes(packed):
+    """uint8 nibble pairs -> (low, high) planes of sign-extended int8 codes.
+
+    The low plane holds the even logical columns, the high plane the odd
+    ones; both keep ``packed``'s shape.
+    """
+    w = packed.astype(jnp.int32)
+    lo = w & 0xF
+    hi = (w >> 4) & 0xF
     lo = jnp.where(lo >= 8, lo - 16, lo)
     hi = jnp.where(hi >= 8, hi - 16, hi)
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(packed.shape[0], packed.shape[1] * 2)
+    return lo.astype(jnp.int8), hi.astype(jnp.int8)
 
 
-def _qmatmul_packed_kernel(x_ref, w_ref, scale_ref, bias_ref, rs_ref, o_ref,
-                           acc_ref, *, nk: int, out_dtype):
+def _qmatmul_kernel(x_ref, w_ref, scale_ref, bias_ref, rs_ref, o_ref,
+                    acc_ref, *, nk: int, out_dtype, packed: bool):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _unpack_nibbles(w_ref[...])
-    acc_ref[...] += jnp.dot(x_ref[...], w.T, preferred_element_type=jnp.int32)
+    if packed:
+        # x_ref holds the (even, odd) column planes of this X tile.
+        lo, hi = nibble_planes(w_ref[...])
+        acc_ref[...] += (
+            jnp.dot(x_ref[0], lo.T, preferred_element_type=jnp.int32)
+            + jnp.dot(x_ref[1], hi.T, preferred_element_type=jnp.int32))
+    else:
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...].T,
+                                preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -78,14 +77,16 @@ def _qmatmul_packed_kernel(x_ref, w_ref, scale_ref, bias_ref, rs_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "out_dtype",
                                              "interpret", "packed"))
 def qmatmul(x_q, w_q, scale, bias=None, row_scale=None, *, bm=128, bn=128,
-            bk=512, out_dtype=jnp.float32, interpret=True, packed=False):
+            bk=512, out_dtype=jnp.float32, interpret=None, packed=False):
     """x_q (M, K) int8 @ w_q (N, K) int8 -> (M, N) float, fused epilogue.
 
     ``scale`` (N,) f32 folds the per-tensor input step and per-channel weight
     step (dx_bar * dw).  ``row_scale`` (M,) optionally refines ``dx_bar`` to
     a per-input-row step (decode batches quantize each sequence on its own
     grid); the epilogue then applies ``scale[n] * row_scale[m]``.
-    ``packed=True`` takes w_q as (N, K//2) uint8 nibbles.
+    ``packed=True`` takes w_q as (N, K//2) uint8 nibbles; ``bk`` must then be
+    a multiple of 256 so each nibble plane tile stays lane-aligned.
+    ``interpret``: see :func:`repro.kernels.interpret_mode`.
     """
     m, kdim = x_q.shape
     n = w_q.shape[0]
@@ -113,15 +114,23 @@ def qmatmul(x_q, w_q, scale, bias=None, row_scale=None, *, bm=128, bn=128,
     scale2 = scale.reshape(1, nn).astype(jnp.float32)
     bias2 = bias.reshape(1, nn).astype(jnp.float32)
     rs2 = row_scale.reshape(mm, 1).astype(jnp.float32)
-    kern = _qmatmul_packed_kernel if packed else _qmatmul_kernel
-    wb = bk // 2 if packed else bk
+    if packed:
+        # (2, M, K/2): the even and odd columns of X, matching the weight's
+        # low and high nibble planes.
+        x_q = jnp.stack([x_q[:, 0::2], x_q[:, 1::2]])
+        xspec = pl.BlockSpec((2, bm, bk // 2), lambda i, j, k: (0, i, k))
+        wspec = pl.BlockSpec((bn, bk // 2), lambda i, j, k: (j, k))
+    else:
+        xspec = pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))
+        wspec = pl.BlockSpec((bn, bk), lambda i, j, k: (j, k))
 
     out = pl.pallas_call(
-        functools.partial(kern, nk=nk, out_dtype=out_dtype),
+        functools.partial(_qmatmul_kernel, nk=nk, out_dtype=out_dtype,
+                          packed=packed),
         grid=(nm, nn_blocks, nk),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, wb), lambda i, j, k: (j, k)),
+            xspec,
+            wspec,
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
@@ -129,6 +138,6 @@ def qmatmul(x_q, w_q, scale, bias=None, row_scale=None, *, bm=128, bn=128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x_q, w_q, scale2, bias2, rs2)
     return out[:m, :n]

@@ -58,7 +58,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.softmax2 import exp2_shift
+from repro.core.softmax2 import exp2_shift, pow2, tree_sum
 
 
 def qmatmul_ref(x_q, w_q, scale, bias=None):
@@ -126,7 +126,7 @@ def int_attention_ref(q_q, k_q, v_q, sc, v_scale, *, attn_bits=7,
     x = jnp.maximum(jnp.where(mask, x, -1e30), -120.0)
     m = jnp.floor(jnp.max(x, axis=-1, keepdims=True))
     e = jnp.where(x <= -120.0, 0.0, exp2_shift(x - m))
-    s = jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+    s = jnp.maximum(tree_sum(e), 1e-30)
     dattn = (2.0 / qmax) / s                      # power-of-two Sigma grid
     p_q = jnp.clip(jnp.round(e * (qmax / 2.0)), 0, qmax)
     pv = jnp.einsum("hqk,hkd->hqd", p_q.astype(jnp.int32),
@@ -170,10 +170,10 @@ def int_attention_ref_streamed(q_q, k_q, v_q, sc, v_scale, *, bk,
         m_new = jnp.maximum(m_old, jnp.floor(jnp.max(x, -1, keepdims=True)))
         e = jnp.where(x <= -120.0, 0.0, exp2_shift(x - m_new))
         p_q = jnp.clip(jnp.round(e * (qmax / 2.0)), 0, qmax)
-        r = jnp.exp2(m_old - m_new)               # exact: both integers
+        r = pow2(m_old - m_new)                   # exact: both integers
         blk = jnp.einsum("hqk,hkd->hqd", p_q.astype(jnp.int32),
                          v.astype(jnp.int32))
-        return (m_new, s_run * r + jnp.sum(e, -1, keepdims=True),
+        return (m_new, s_run * r + tree_sum(e),
                 pv * r + blk.astype(jnp.float32)), None
 
     init = (jnp.full((h, sq, 1), -1e30), jnp.zeros((h, sq, 1)),
@@ -226,7 +226,7 @@ def int_decode_attention_ref(q_q, k_q, v_q, sc, v_scale, k_positions, pos, *,
     if bk is None:                                # full-row grid
         m = jnp.floor(jnp.max(x, axis=-1, keepdims=True))
         e = jnp.where(x <= -120.0, 0.0, exp2_shift(x - m))
-        s = jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+        s = jnp.maximum(tree_sum(e), 1e-30)
         p_q = jnp.clip(jnp.round(e * (qmax / 2.0)), 0, qmax)
         if v_factor is not None:
             pv = jnp.einsum("hgk,hkd->hgd", p_q * v_factor[None, None, :],
@@ -253,12 +253,12 @@ def int_decode_attention_ref(q_q, k_q, v_q, sc, v_scale, k_positions, pos, *,
         m_new = jnp.maximum(m_old, jnp.floor(jnp.max(xb, -1, keepdims=True)))
         e = jnp.where(xb <= -120.0, 0.0, exp2_shift(xb - m_new))
         p_q = jnp.clip(jnp.round(e * (qmax / 2.0)), 0, qmax)
-        r = jnp.exp2(m_old - m_new)               # exact: both integers
+        r = pow2(m_old - m_new)                   # exact: both integers
         blk = jnp.einsum("hgk,hkd->hgd", p_q.astype(jnp.int32),
                          vb.astype(jnp.int32)).astype(jnp.float32)
         if v_factor is not None:                  # per-block dv, kernel-wise
             blk = blk * (_head_sc(v_scale, h) * vf_blk[t])
-        return (m_new, s_run * r + jnp.sum(e, -1, keepdims=True),
+        return (m_new, s_run * r + tree_sum(e),
                 pv * r + blk), None
 
     init = (jnp.full((h, g, 1), -1e30), jnp.zeros((h, g, 1)),

@@ -1,5 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+import jax  # noqa: E402
+# The 512 fake devices are host devices: never take an attached TPU.
+jax.config.update("jax_platforms", "cpu")
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

@@ -183,6 +183,13 @@ from repro.models import lm
 from repro.runtime import faults as faults_mod
 from repro.runtime.watchdog import Watchdog
 
+# The serving programs round bf16 values exactly where the program says.
+# By default XLA may keep a bf16 result in f32 inside a fusion and round it
+# only where the fusion ends; the fusions around a Pallas kernel end in
+# other places than those around XLA's own dot, so the two kernel backends
+# would serve different tokens (measured on a v5e).
+COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
 
 class Status:
     """Request lifecycle states (see the module docstring's diagram)."""
@@ -431,11 +438,12 @@ class PagedEngine:
             return lm.admission_prefill(params, batch, cfg, cache, rows,
                                         page_table, prefix_len=prefix_len)
 
-        self._step = jax.jit(step_fn)
+        self._step = jax.jit(step_fn, compiler_options=COMPILER_OPTIONS)
         self._step_xla = None             # forced-fallback twin, traced lazily
         # Retraces once per (bucket, admission-batch-width, prefix-length)
         # shape triple.
-        self._admit_prefill = jax.jit(admit_fn, static_argnums=(5,))
+        self._admit_prefill = jax.jit(admit_fn, static_argnums=(5,),
+                                      compiler_options=COMPILER_OPTIONS)
 
     # -- allocator ---------------------------------------------------------
 
@@ -789,7 +797,8 @@ class PagedEngine:
             def step_fn(params, tok, cache):
                 return lm.decode_step(params, tok, cache, cfg)
 
-            self._step_xla = jax.jit(step_fn)
+            self._step_xla = jax.jit(step_fn,
+                                     compiler_options=COMPILER_OPTIONS)
         return self._step_xla
 
     # -- drain / prefill ---------------------------------------------------

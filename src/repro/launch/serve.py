@@ -36,21 +36,29 @@ releases in-flight rows with their partial outputs kept, and still emits
 the final report — flagged ``"preempted": true`` — before exiting with
 ``PREEMPTED_EXIT_CODE``.  ``--deadline-s`` expires queued requests,
 ``--audit-every N`` runs the engine-wide invariant audit every N steps
-(failures are counted, never fatal, in production), and the report's
-``failures`` section surfaces the engine's preemption / resume / cancel /
-expiry / watchdog / audit counters.
+(failures are counted, never fatal, in production; one more audit runs when
+the loop ends, so ``audit_violations`` always covers the final state), and
+the report's ``failures`` section surfaces the engine's preemption /
+resume / cancel / expiry / watchdog / audit counters.
+
+On a TPU the int graph runs the compiled Pallas kernels by default
+(``--backend`` overrides).  Run as a script, the CLI keeps JAX's
+persistent compile cache where ``JAX_COMPILATION_CACHE_DIR`` says, else in
+``.jax_cache/`` at the root of the checkout (:func:`use_compile_cache`).
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.api import QuantConfig, integerize_params
+from repro.core.api import QuantConfig
 from repro.kernels import dispatch
 from repro.launch.engine import PagedEngine, Request
 from repro.models import lm
@@ -77,6 +85,22 @@ failure handling:
                       every N steps; failures are counted in the report's
                       "failures" section, never fatal in serving.
 """
+
+
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache():
+    """Point JAX's persistent compile cache at a fixed directory.
+
+    JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself when it is set; otherwise
+    the cache goes to ``.jax_cache/`` at the root of the checkout.  The
+    path is part of the cache key, so it must not move between runs.  For
+    entry points only: library code never sets a cache.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(CHECKOUT / ".jax_cache"))
 
 
 def serve(cfg: lm.LMConfig, params, prompts, *, gen_tokens: int = 16,
@@ -146,6 +170,7 @@ def serve(cfg: lm.LMConfig, params, prompts, *, gen_tokens: int = 16,
             break
         if not engine.step():
             break
+    engine.audit(raise_on_fail=False)
     total_s = time.perf_counter() - t0
 
     gen = np.zeros((len(reqs), gen_tokens), np.int32)
@@ -189,7 +214,8 @@ def main(argv=None):
     ap.add_argument("--mode", choices=["int", "float"], default="int")
     ap.add_argument("--backend", choices=["xla", "pallas"], default=None,
                     help="kernel backend for the int serving graph "
-                         "(default: REPRO_KERNEL_BACKEND / xla)")
+                         "(default: REPRO_KERNEL_BACKEND, else pallas on "
+                         "a TPU and xla elsewhere)")
     ap.add_argument("--wbits", type=int, default=4)
     ap.add_argument("--kv-bits", type=int, default=8, choices=[4, 8])
     ap.add_argument("--batch", type=int, default=4,
@@ -238,12 +264,13 @@ def main(argv=None):
     from repro.configs.registry import smoke_config
     cfg = smoke_config(args.arch)
     key = jax.random.PRNGKey(0)
-    params = lm.init_params(key, cfg)
     if args.mode == "int":
         qc = QuantConfig(w_bits=args.wbits, a_bits=8, attn_bits=7,
                          kv_bits=args.kv_bits, mode="int")
-        params = integerize_params(params, qc)
+        params = lm.init_int_params(key, cfg, qc)
         cfg = cfg.replace(quant=qc)
+    else:
+        params = lm.init_params(key, cfg)
     n_req = args.requests or args.batch
     rng = np.random.RandomState(0)
     # Staggered prompt lengths: the multi-tenant regime the paged cache is
@@ -305,4 +332,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

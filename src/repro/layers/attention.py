@@ -64,7 +64,7 @@ import jax.numpy as jnp
 from repro.core import quant
 from repro.core.api import QuantConfig
 from repro.core.quant import ACC_DTYPE
-from repro.core.softmax2 import LOG2E, exp2_shift
+from repro.core.softmax2 import LOG2E, exp2_shift, tree_sum
 from repro.models.scan_util import scan as _scan
 
 NEG_BIG = -1e9
@@ -150,15 +150,17 @@ def _row_attention(q, k, v, q_pos, k_pos, spec: AttnSpec,
         vq = _as_q_rows(v, cfg.a_bits)
         acc = jnp.einsum("bhgqd,bhkd->bhgqk", qq.q, kq.q,
                          preferred_element_type=ACC_DTYPE)
-        x = acc.astype(jnp.float32) * (scale * LOG2E * _sc5(qq.scale)
-                                       * _sc5(kq.scale))
+        # The query factor is pinned: the kernel path forms the same
+        # product, and XLA would otherwise reorder the chain per program.
+        x = acc.astype(jnp.float32) * (
+            quant.pin(scale * LOG2E * _sc5(qq.scale)) * _sc5(kq.scale))
         x = jnp.where(mask, x, NEG_BIG)
         x = jnp.maximum(x, -120.0)                          # keep 2^x in range
         m = jnp.floor(jnp.max(x, axis=-1, keepdims=True))   # integer shift
         e = exp2_shift(x - m) if cfg.softmax == "base2" \
             else jnp.exp2(x - m)
         e = jnp.where(mask & (x > -120.0), e, 0.0)
-        sigma = jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+        sigma = jnp.maximum(tree_sum(e), 1e-30)
         # Sigma-scaled quantizer (paper §IV-B) on the power-of-two grid:
         # code step 2/qmax relative to 2^m (m integer), so thresholds are
         # pure shifts of Sigma and the same codes can be emitted online by
@@ -356,7 +358,7 @@ def prefix_prefill_attention(q, k, v, k_pre, v_pre, pre_k_scale, pre_v_scale,
             e = exp2_shift(x - m) if cfg.softmax == "base2" \
                 else jnp.exp2(x - m)
             e = jnp.where(mask & (x > -120.0), e, 0.0)
-            sigma = jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+            sigma = jnp.maximum(tree_sum(e), 1e-30)
             dattn = (2.0 / qmaxp) / sigma
             p_q = jnp.clip(jnp.round(e * (qmaxp / 2.0)), 0,
                            qmaxp).astype(ACC_DTYPE)

@@ -55,12 +55,13 @@ what makes a shared prefix bit-identical to a privately prefilled one.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.api import QuantConfig, dense
+from repro.core.api import QuantConfig, dense, integerize_params
 from repro.core.quant import QTensor
 from repro.layers import moe as moe_lib
 from repro.layers.attention import (AttnSpec, attention, paged_attention,
@@ -174,23 +175,51 @@ def init_block(key, cfg: LMConfig, kind: str) -> dict:
     return p
 
 
+def _init_unit(key, cfg: LMConfig) -> dict:
+    unit = tuple(cfg.block_pattern)
+    kk = jax.random.split(key, len(unit))
+    return {f"b{j}": init_block(kk[j], cfg, kind)
+            for j, kind in enumerate(unit)}
+
+
+def _init_top(ks, cfg: LMConfig) -> dict:
+    return {"embed": init_embed(ks[0], cfg.vocab, cfg.d_model, cfg.jdtype),
+            "final_norm": init_norm(cfg.d_model, cfg.norm),
+            "lm_head": _lin(ks[1], cfg.d_model, cfg.vocab, cfg.jdtype)}
+
+
 def init_params(key, cfg: LMConfig) -> dict:
     unit, n_units, rem = unit_structure(cfg)
     ks = jax.random.split(key, 4 + len(rem))
-    params = {"embed": init_embed(ks[0], cfg.vocab, cfg.d_model, cfg.jdtype),
-              "final_norm": init_norm(cfg.d_model, cfg.norm),
-              "lm_head": _lin(ks[1], cfg.d_model, cfg.vocab, cfg.jdtype)}
-
-    def init_unit(k):
-        kk = jax.random.split(k, len(unit))
-        return {f"b{j}": init_block(kk[j], cfg, kind)
-                for j, kind in enumerate(unit)}
-
+    params = _init_top(ks, cfg)
     if n_units:
         unit_keys = jax.random.split(ks[2], n_units)
-        params["units"] = jax.vmap(init_unit)(unit_keys)
+        params["units"] = jax.vmap(lambda k: _init_unit(k, cfg))(unit_keys)
     for i, kind in enumerate(rem):
         params[f"rem{i}"] = init_block(ks[4 + i], cfg, kind)
+    return params
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def init_int_params(key, cfg: LMConfig, qc: QuantConfig) -> dict:
+    """Serving parameters at any width, without the float model.
+
+    Equals ``integerize_params(init_params(key, cfg), qc)`` bit for bit, but
+    initialises and integerises one pattern unit at a time (``lax.map``
+    over the unit keys), so the device holds the integer codes and scales
+    plus a single unit's float weights — ChatGLM3-6B's float model alone
+    would not fit one 16 GB chip.
+    """
+    unit, n_units, rem = unit_structure(cfg)
+    ks = jax.random.split(key, 4 + len(rem))
+    params = integerize_params(_init_top(ks, cfg), qc)
+    if n_units:
+        params["units"] = jax.lax.map(
+            lambda k: integerize_params(_init_unit(k, cfg), qc),
+            jax.random.split(ks[2], n_units))
+    for i, kind in enumerate(rem):
+        params[f"rem{i}"] = integerize_params(
+            init_block(ks[4 + i], cfg, kind), qc)
     return params
 
 
@@ -379,14 +408,14 @@ def _paged_write_prefill(cache, k, v, positions, lengths, page_table, mode,
     row = jnp.mod(positions, ps)
     new_cache = dict(cache)
     if mode == "int":
-        from repro.core.quant import pack_int4, qrange
+        from repro.core.quant import pack_int4, pin, qrange
         kv4 = qcfg.kv_bits == 4
         qmin, qmax = qrange(4) if kv4 else qrange(8)
         vmask = valid[:, None, :, None]
 
         def rowscale(t):
             amax = jnp.max(jnp.abs(t) * vmask, axis=(1, 2, 3))
-            return jnp.maximum(amax.astype(jnp.float32), 1e-8) / qmax
+            return pin(jnp.maximum(amax.astype(jnp.float32), 1e-8) / qmax)
 
         ksc, vsc = rowscale(k), rowscale(v)
         new_cache["k_scale"], new_cache["v_scale"] = ksc, vsc

@@ -356,6 +356,52 @@ def test_lm_prefill_and_decode_both_dispatch():
         assert bool(jnp.all(jnp.isfinite(logits2)))
 
 
+def test_platform_picks_backend_and_kernel_mode(monkeypatch):
+    """With no explicit override the platform decides: pallas and compiled
+    kernels on a TPU, xla and the interpreter elsewhere — a caller can
+    never run a kernel interpreted on the chip."""
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(dispatch, "_backend", [None])
+    assert dispatch.get_backend() == "xla"
+    assert interpret_mode() and interpret_mode(True)
+    assert not interpret_mode(False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert dispatch.get_backend() == "pallas"
+    assert not interpret_mode() and not interpret_mode(True)
+    with dispatch.use_backend("xla"):
+        assert dispatch.get_backend() == "xla"
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_lm_bf16_backend_parity_exact(step):
+    """bf16 activations: both backends serve the same bits.
+
+    Each backend gets a fresh trace (the backend is read at trace time).
+    Scale arithmetic runs in f32, so no bf16 rounding is left for XLA to
+    place differently around a kernel than around its own dot.
+    """
+    from repro.configs.registry import smoke_config
+    from repro.models import lm
+    qc = QuantConfig(w_bits=4, a_bits=8, attn_bits=7, kv_bits=8, mode="int")
+    cfg = smoke_config("chatglm3-6b").replace(dtype="bfloat16", q_chunk=32,
+                                              quant=qc)
+    params = lm.init_int_params(jax.random.PRNGKey(0), cfg, qc)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0, cfg.vocab)
+    out = {}
+    for backend in ("pallas", "xla"):
+        with dispatch.use_backend(backend):
+            logits, cache = jax.jit(
+                lambda p, t: lm.prefill(p, {"tokens": t}, cfg, max_len=66))(
+                    params, toks)
+            if step == "decode":
+                tok = jnp.argmax(logits, -1).astype(jnp.int32)
+                logits, _ = jax.jit(
+                    lambda p, t, c: lm.decode_step(p, t, c, cfg))(
+                        params, tok, cache)
+        out[backend] = np.asarray(logits, np.float32)
+    np.testing.assert_array_equal(out["pallas"], out["xla"])
+
+
 # ---------------------------------------------------------------------------
 # benchmark harness
 # ---------------------------------------------------------------------------

@@ -18,8 +18,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def _run(body: str) -> str:
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=SRC)
-    env.pop("JAX_PLATFORMS", None)
+               PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", body], capture_output=True,
                        text=True, env=env, timeout=420)
     assert r.returncode == 0, r.stdout + "\n" + r.stderr

@@ -139,3 +139,56 @@ def test_int4_packed_kv_cache():
         l4, cache4 = lm.decode_step(ip, tok, cache4, cfg_f.replace(quant=qc4))
         corr = float(jnp.corrcoef(l8.ravel(), l4.ravel())[0, 1])
         assert corr > 0.95, corr
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_streamed_int_builder_matches_integerize(dtype):
+    """lm.init_int_params (one unit at a time, no float model held) gives
+    the codes and scales of integerizing the whole float model, bit for bit.
+
+    The reference runs under jit like the builder: XLA's CPU backend draws
+    slightly different float weights op by op than fused.
+    """
+    from repro.configs.registry import smoke_config
+    cfg = smoke_config("chatglm3-6b").replace(dtype=dtype)
+    qc = QuantConfig(w_bits=4, a_bits=8, attn_bits=7, kv_bits=8, mode="int")
+    key = jax.random.PRNGKey(3)
+    got = lm.init_int_params(key, cfg, qc)
+    want = jax.jit(lambda k: integerize_params(lm.init_params(k, cfg), qc))(
+        key)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape, path
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+def test_chip_smoke_refuses_cpu():
+    """chip_smoke.py never falls back: without a TPU it exits non-zero."""
+    import chip_smoke
+    with pytest.raises(SystemExit) as exc:
+        chip_smoke.require_tpu()
+    assert exc.value.code not in (0, None)
+    assert "no TPU found" in str(exc.value.code)
+
+
+def test_compile_cache_dir_of_entry_points(monkeypatch):
+    """Entry points keep JAX's compile cache where JAX_COMPILATION_CACHE_DIR
+    says, else in the checkout's git-ignored .jax_cache/ — a fixed path, so
+    a later run finds what an earlier one compiled."""
+    from repro.launch import serve
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/set/by/environment")
+    serve.use_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        serve.use_compile_cache()
+        path = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert path == str(serve.CHECKOUT / ".jax_cache")
+    ignored = (serve.CHECKOUT / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
