@@ -632,7 +632,6 @@ def paged_loop(quick=False):
                 "requests": len(reqs), "engine_steps": eng.step_count,
                 "prefill_calls": eng.prefill_calls,
                 "tok_per_s": sum(len(r.tokens) for r in reqs) / dt,
-                "per_seq_tok_per_s": [round(r.tok_per_s, 2) for r in reqs],
                 "stats": dispatch.snapshot()}
     return res
 

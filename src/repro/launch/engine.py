@@ -165,6 +165,40 @@ the chunked path), per-sequence EOS eviction, and the prefix registry /
 CoW machinery described above.  A blocked (but servable) request stops
 admission behind it within its scan — except requests in preemption
 backoff, which are skipped without blocking.
+
+Profiler spans and request stamps
+=================================
+
+Each engine step writes host spans (``jax.profiler.TraceAnnotation``)
+into the profiler's trace, on the clock the device operations are
+aligned to, so an idle gap on the device can be read against what the
+host was doing.  With no trace running a span costs under a microsecond
+(0.4-0.8 us on a TPU v5e host, about 5 spans a step).  Spans nest on the
+calling thread:
+
+- ``engine.step`` (``step``): the whole of :meth:`PagedEngine.step`;
+- ``engine.admit`` (``admitted``): lifecycle, fault hooks and the
+  admission drain — the scan, the pressure ladder, preemption, CoW
+  copies (one-shot prefills nest inside it);
+- ``engine.prefill`` (``start``, ``width``, ``bucket``, ``tokens``,
+  ``rids``): one admission-prefill launch — the host arrays and the
+  dispatch; the launch shape names the program a compilation belongs to;
+- ``engine.prefill_sync``: the first-token readback of a launch and the
+  bookkeeping after it;
+- ``engine.decode`` (``rows``): the page-table push, the token transfer
+  and the decode dispatch;
+- ``engine.decode_sync``: the argmax and finiteness readbacks;
+- ``engine.commit``: token append, replay, finish or evict, quarantine
+  and the periodic audit.
+
+``rids`` is the request id, shared across spans.  Every request carries
+host-clock stamps (``time.perf_counter()``), each set at its first
+transition and kept across preemption: ``submit_s`` (first
+:meth:`PagedEngine.submit`; the deadline clock), ``admit_s`` (first
+admission) and ``first_token_s`` (first generated token read back).  The
+token-budget packer counts ``prefill_steps`` (steps the request spent
+PREFILLING) and ``prefill_stalls`` (those in which none of its chunks
+launched).
 """
 from __future__ import annotations
 
@@ -177,6 +211,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels import dispatch
 from repro.models import lm
@@ -313,14 +348,20 @@ class Request:
     status: str = Status.QUEUED
     admitted_step: int = -1
     finished_step: int = -1
-    decode_s: float = 0.0                 # wall time while this row decoded
     error: Optional[str] = None           # set when the request failed
     preemptions: int = 0                  # times this request lost its row
     cancel_requested: bool = False
+    # host-clock stamps (time.perf_counter()), set once, kept on resume:
+    submit_s: Optional[float] = None      # first submit (deadline clock)
+    admit_s: Optional[float] = None       # first admission
+    first_token_s: Optional[float] = None  # first generated token read back
+    # token-budget packer: steps spent PREFILLING, and those of them in
+    # which no chunk of this request launched
+    prefill_steps: int = 0
+    prefill_stalls: int = 0
     # engine-internal bookkeeping:
     _arrival: int = -1                    # global FIFO order within priority
     _submit_step: int = -1                # latest (re)queue step (TTL clock)
-    _submit_time: float = 0.0             # first submit wall time (deadline)
     _not_before_step: int = 0             # preemption backoff gate
     _replay: Optional[list] = None        # resume: tokens left to replay
     _resuming: bool = False               # admitted as a recompute
@@ -338,10 +379,6 @@ class Request:
     @property
     def failed(self) -> bool:
         return self.error is not None
-
-    @property
-    def tok_per_s(self) -> float:
-        return len(self.tokens) / max(self.decode_s, 1e-9)
 
 
 def _bucket(n: int, buckets) -> int:
@@ -534,7 +571,8 @@ class PagedEngine:
         req._arrival = self._arrival_seq
         self._arrival_seq += 1
         req._submit_step = self.step_count
-        req._submit_time = time.monotonic()
+        if req.submit_s is None:
+            req.submit_s = time.perf_counter()
         self.queue.append(req)
 
     def cancel(self, rid: int) -> bool:
@@ -616,6 +654,8 @@ class PagedEngine:
         self.pos[row] = -1 if chunked else len(req.prompt)
         self.row_req[row] = req
         req.admitted_step = self.step_count
+        if req.admit_s is None:
+            req.admit_s = time.perf_counter()
         req.status = Status.PREFILLING if chunked else Status.RUNNING
         req._chunk_start = plen
         req._chunk_pos = plen
@@ -755,7 +795,7 @@ class PagedEngine:
 
     def _process_lifecycle(self):
         """Cancellation (queued + mid-flight) and queued-state expiry."""
-        now = time.monotonic()
+        now = time.perf_counter()
         keep = []
         for req in self.queue:
             if req.cancel_requested:
@@ -768,7 +808,7 @@ class PagedEngine:
                                f"expired after {req.ttl_steps} queued steps")
                 dispatch.STATS["expired"] += 1
             elif (req.deadline_s is not None
-                  and now - req._submit_time >= req.deadline_s):
+                  and now - req.submit_s >= req.deadline_s):
                 self._terminal(req, Status.TIMED_OUT,
                                f"deadline {req.deadline_s}s passed while "
                                f"queued")
@@ -821,6 +861,7 @@ class PagedEngine:
         merely-blocked servable request stops admission behind it (FIFO
         within priority) after the pressure ladder — registry LRU
         reclaim, then victim preemption (:meth:`_make_room`) — fails.
+        Returns the number of requests admitted.
         """
         admits = []
         admitted_now: set = set()
@@ -869,6 +910,7 @@ class PagedEngine:
             groups.setdefault((plen, b), []).append((req, row))
         for plen, bucket in sorted(groups):
             self._prefill_group(bucket, groups[(plen, bucket)], plen)
+        return len(admits)
 
     def _count_chunk(self, tokens: int, first: bool):
         """Prefill accounting (module docstring): one logical call per
@@ -902,14 +944,17 @@ class PagedEngine:
         while cur < plen:
             end = plen if one_shot else self._next_cut(cur, plen)
             bucket = _bucket(end - cur, self.prefill_buckets)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :end - cur] = req.prompt[cur:end]
-            _, self.cache = self._admit_prefill(
-                self.params, {"tokens": jnp.asarray(toks),
-                              "lengths": jnp.asarray([end - cur],
-                                                     np.int32)},
-                self.cache, jnp.asarray([row], np.int32),
-                jnp.asarray(ptw), cur)
+            with TraceAnnotation("engine.prefill", start=cur, width=1,
+                                 bucket=bucket, tokens=end - cur,
+                                 rids=str(req.rid)):
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :end - cur] = req.prompt[cur:end]
+                _, self.cache = self._admit_prefill(
+                    self.params, {"tokens": jnp.asarray(toks),
+                                  "lengths": jnp.asarray([end - cur],
+                                                         np.int32)},
+                    self.cache, jnp.asarray([row], np.int32),
+                    jnp.asarray(ptw), cur)
             self._count_chunk(end - cur, first=cur == 0)
             cur = end
         self.prefix_prefills += 1
@@ -942,24 +987,35 @@ class PagedEngine:
         it was preempted after already recording its final token.
         Non-final rows stay PREFILLING, frozen at pos -1."""
         w = len(items)
-        toks = np.zeros((w, bucket), np.int32)
-        lens = np.zeros((w,), np.int32)
-        ptw = np.full((w, self.max_pages), -1, np.int32)
-        rows = np.zeros((w,), np.int32)
-        for j, (req, row, end) in enumerate(items):
-            seg = req.prompt[start:end]
-            toks[j, :len(seg)] = seg
-            lens[j] = len(seg)
-            ptw[j] = self.page_table[row]
-            rows[j] = row
-        logits, self.cache = self._admit_prefill(
-            self.params, {"tokens": jnp.asarray(toks),
-                          "lengths": jnp.asarray(lens)},
-            self.cache, jnp.asarray(rows), jnp.asarray(ptw), start)
-        self._count_chunk(int(lens.sum()),
-                          first=any(req._chunk_start == start
-                                    for req, _, _ in items))
-        first = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+        tokens = sum(end - start for _, _, end in items)
+        with TraceAnnotation("engine.prefill", start=start, width=w,
+                             bucket=bucket, tokens=tokens,
+                             rids=" ".join(str(req.rid)
+                                           for req, _, _ in items)):
+            toks = np.zeros((w, bucket), np.int32)
+            lens = np.zeros((w,), np.int32)
+            ptw = np.full((w, self.max_pages), -1, np.int32)
+            rows = np.zeros((w,), np.int32)
+            for j, (req, row, end) in enumerate(items):
+                seg = req.prompt[start:end]
+                toks[j, :len(seg)] = seg
+                lens[j] = len(seg)
+                ptw[j] = self.page_table[row]
+                rows[j] = row
+            logits, self.cache = self._admit_prefill(
+                self.params, {"tokens": jnp.asarray(toks),
+                              "lengths": jnp.asarray(lens)},
+                self.cache, jnp.asarray(rows), jnp.asarray(ptw), start)
+            self._count_chunk(tokens, first=any(req._chunk_start == start
+                                                for req, _, _ in items))
+        with TraceAnnotation("engine.prefill_sync"):
+            first = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+            self._land_chunk(items, first)
+
+    def _land_chunk(self, items, first):
+        """After a launch's readback: advance each row's chunk cursor, and
+        seed generation (or replay) for rows whose final chunk landed."""
+        now = time.perf_counter()
         for j, (req, row, end) in enumerate(items):
             req._chunk_pos = end
             if end < len(req.prompt):
@@ -987,6 +1043,8 @@ class PagedEngine:
                 continue
             self.next_tok[row] = first[j]
             req.tokens.append(int(first[j]))
+            if req.first_token_s is None:
+                req.first_token_s = now
             self._maybe_finish(row, int(first[j]))
 
     def _advance_prefills(self):
@@ -1000,7 +1058,9 @@ class PagedEngine:
         order, so a row's chunk i+1 always reads codes chunk i already
         wrote.  The budget changes only the launch schedule — the cut
         plan (and therefore every code and token) is fixed by
-        :meth:`_next_cut`."""
+        :meth:`_next_cut`.  Every pending request counts the step in
+        ``prefill_steps``, and in ``prefill_stalls`` when none of its
+        chunks was packed."""
         pending = sorted(
             (req.admitted_step, req._arrival, row)
             for row, req in enumerate(self.row_req)
@@ -1027,6 +1087,11 @@ class PagedEngine:
                 cursors[row] = end
                 spent += end - cur
                 progressed = True
+        launched = {row for _, row, _, _ in taken}
+        for row in order:
+            req = self.row_req[row]
+            req.prefill_steps += 1
+            req.prefill_stalls += row not in launched
         groups: dict[tuple, list] = {}
         for req, row, cur, end in taken:
             b = _bucket(end - cur, self.prefill_buckets)
@@ -1160,9 +1225,14 @@ class PagedEngine:
 
         Returns False when there is nothing left to do.
         """
-        ev = self._apply_faults_pre()
-        self._process_lifecycle()
-        self._drain_queue()
+        with TraceAnnotation("engine.step", step=self.step_count):
+            return self._run_step()
+
+    def _run_step(self) -> bool:
+        with TraceAnnotation("engine.admit") as span:
+            ev = self._apply_faults_pre()
+            self._process_lifecycle()
+            span.set_metadata(admitted=self._drain_queue())
         self._advance_prefills()
         active = [r for r, req in enumerate(self.row_req)
                   if req is not None and req.status is Status.RUNNING]
@@ -1174,37 +1244,47 @@ class PagedEngine:
                 self._audit_maybe()
                 return True
             return False
-        self._push_tables()
-        step_fn = self._step
-        if ev is not None and ev.force_xla:
-            step_fn = self._step_fallback()
-            dispatch.STATS["forced_xla_steps"] += 1
-        self.watchdog.start()
-        if ev is not None and ev.stall_s:
-            time.sleep(ev.stall_s)              # straggler, seen by the EMA
-        t0 = time.perf_counter()
-        if step_fn is self._step:
-            logits, self.cache = step_fn(
-                self.params, jnp.asarray(self.next_tok)[:, None], self.cache)
-        else:
-            # Backend choice is trace-time: the twin must (re)trace and run
-            # under the forced backend.
-            with dispatch.use_backend("xla"):
+        with TraceAnnotation("engine.decode", rows=len(active)):
+            self._push_tables()
+            step_fn = self._step
+            if ev is not None and ev.force_xla:
+                step_fn = self._step_fallback()
+                dispatch.STATS["forced_xla_steps"] += 1
+            self.watchdog.start()
+            if ev is not None and ev.stall_s:
+                time.sleep(ev.stall_s)          # straggler, seen by the EMA
+            if step_fn is self._step:
                 logits, self.cache = step_fn(
                     self.params, jnp.asarray(self.next_tok)[:, None],
                     self.cache)
-        if ev is not None and ev.nan_row is not None:
-            logits = faults_mod.corrupt_rows(
-                logits, [active[ev.nan_row % len(active)]])
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
-        finite = np.asarray(jnp.all(jnp.isfinite(logits[:, 0]), axis=-1))
-        dt = time.perf_counter() - t0
+            else:
+                # Backend choice is trace-time: the twin must (re)trace and
+                # run under the forced backend.
+                with dispatch.use_backend("xla"):
+                    logits, self.cache = step_fn(
+                        self.params, jnp.asarray(self.next_tok)[:, None],
+                        self.cache)
+            if ev is not None and ev.nan_row is not None:
+                logits = faults_mod.corrupt_rows(
+                    logits, [active[ev.nan_row % len(active)]])
+        with TraceAnnotation("engine.decode_sync"):
+            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+            finite = np.asarray(jnp.all(jnp.isfinite(logits[:, 0]),
+                                        axis=-1))
         self.watchdog.stop()
+        with TraceAnnotation("engine.commit"):
+            self._commit(active, nxt, finite)
+            self._audit_maybe()
+        return True
+
+    def _commit(self, active, nxt, finite):
+        """After the decode readback: quarantine non-finite rows, replay
+        or append each active row's token, and finish rows that are
+        done."""
         self.pos[self.pos >= 0] += 1          # mirror the device update
         self.step_count += 1
         for row in active:
             req = self.row_req[row]
-            req.decode_s += dt
             if not finite[row]:
                 self._quarantine(row)
                 continue
@@ -1227,8 +1307,6 @@ class PagedEngine:
             req.tokens.append(int(nxt[row]))
             self.next_tok[row] = nxt[row]
             self._maybe_finish(row, int(nxt[row]))
-        self._audit_maybe()
-        return True
 
     def shutdown(self):
         """Graceful drain (SIGTERM/SIGUSR1 path): stop serving NOW.

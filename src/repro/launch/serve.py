@@ -13,12 +13,16 @@ rows are never decoded again.  ``--shared-prefix N`` models
 system-prompt-heavy traffic: every request carries the same N-token
 prefix declared as a cache breakpoint, so the engine prefills it ONCE and
 aliases its refcounted pages across all requests (``prefix_prefills`` /
-``shared_prefix_hits`` in the report).  The run always reports the kernel-dispatch
-STATS: in CI it is the regression signal that the serving graph really
-traced onto the Pallas kernels (``attention_paged_pallas`` > 0 for the
-decode loop) instead of silently falling back to XLA.  ``--json`` emits
-the whole report as one JSON object on stdout so CI parses it instead of
-grepping log lines.
+``shared_prefix_hits`` in the report).  The report gives the run's
+wall time and total tokens per second, and each sequence's time to first
+token (``ttft_ms``: from the engine's ``submit_s`` stamp to its
+``first_token_s`` stamp, taken when the first token is read back) with
+its p50 and p95 over the sequences that produced one.  The run always
+reports the kernel-dispatch STATS: in CI it is the regression signal
+that the serving graph really traced onto the Pallas kernels
+(``attention_paged_pallas`` > 0 for the decode loop) instead of silently
+falling back to XLA.  ``--json`` emits the whole report as one JSON
+object on stdout so CI parses it instead of grepping log lines.
 
 ``--prefill-chunk`` / ``--prefill-budget`` engage the chunked-prefill
 token-budget scheduler: prompts prefill in page-aligned chunks and every
@@ -103,6 +107,14 @@ def use_compile_cache():
                           str(CHECKOUT / ".jax_cache"))
 
 
+def _ttft_ms(req: Request) -> float | None:
+    """Submit to first generated token on the engine's own stamps, in ms
+    (None when the request produced no token)."""
+    if req.first_token_s is None:
+        return None
+    return (req.first_token_s - req.submit_s) * 1e3
+
+
 def serve(cfg: lm.LMConfig, params, prompts, *, gen_tokens: int = 16,
           max_len: int | None = None, page_size: int = 16,
           eos_id: int | None = None, batch_size: int | None = None,
@@ -177,20 +189,21 @@ def serve(cfg: lm.LMConfig, params, prompts, *, gen_tokens: int = 16,
     for i, r in enumerate(reqs):
         gen[i, :len(r.tokens)] = r.tokens
     n_tok = sum(len(r.tokens) for r in reqs)
-    decode_s = sum(r.decode_s for r in reqs) / max(len(reqs), 1)
+    ttft = [_ttft_ms(r) for r in reqs]
+    served = [t for t in ttft if t is not None]
     snap = dispatch.snapshot()
     return jnp.asarray(gen), {
         "total_s": total_s,
-        "prefill_s": total_s - decode_s,
-        "decode_s": decode_s,
         "tok_per_s": n_tok / max(total_s, 1e-9),
+        "ttft_p50_ms": float(np.percentile(served, 50)) if served else None,
+        "ttft_p95_ms": float(np.percentile(served, 95)) if served else None,
         "per_seq": [{"rid": r.rid, "prompt_len": len(r.prompt),
                      "gen": len(r.tokens),
                      "status": r.status,
                      "admitted_step": r.admitted_step,
                      "finished_step": r.finished_step,
-                     "tok_per_s": r.tok_per_s,
-                     "error": r.error} for r in reqs],
+                     "ttft_ms": t,
+                     "error": r.error} for r, t in zip(reqs, ttft)],
         "engine_steps": engine.step_count,
         "prefill_calls": engine.prefill_calls,
         "prefill_chunks": engine.prefill_chunks,
@@ -303,9 +316,11 @@ def main(argv=None):
                          indent=2))
     else:
         flag = "  PREEMPTED (partial)" if stats["preempted"] else ""
+        ttft = "  ".join(f"ttft {q} {stats[f'ttft_{q}_ms']:.1f}ms"
+                         for q in ("p50", "p95")
+                         if stats[f"ttft_{q}_ms"] is not None)
         print(f"[serve:{args.mode}] total {stats['total_s']:.3f}s  "
-              f"decode {stats['decode_s']:.3f}s  "
-              f"{stats['tok_per_s']:.1f} tok/s  "
+              f"{stats['tok_per_s']:.1f} tok/s  {ttft}  "
               f"steps {stats['engine_steps']}  "
               f"prefills {stats['prefill_calls']}  "
               f"(chunks {stats['prefill_chunks']}, "
@@ -314,8 +329,12 @@ def main(argv=None):
               f"hits {stats['shared_prefix_hits']})  "
               f"rejected {stats['rejected']}{flag}")
         for s in stats["per_seq"]:
-            tail = f"{s['status'].upper()}: {s['error']}" if s["error"] \
-                else f"{s['status']}  {s['tok_per_s']:.1f} tok/s"
+            if s["error"]:
+                tail = f"{s['status'].upper()}: {s['error']}"
+            elif s["ttft_ms"] is None:
+                tail = s["status"]
+            else:
+                tail = f"{s['status']}  ttft {s['ttft_ms']:.1f}ms"
             print(f"  [seq {s['rid']}] prompt {s['prompt_len']:4d}  "
                   f"gen {s['gen']:3d}  admitted@{s['admitted_step']}  "
                   f"finished@{s['finished_step']}  {tail}")

@@ -270,3 +270,115 @@ def test_cancel_and_preempt_between_chunks():
     assert victim.done and victim.tokens == ref.tokens
     assert eng2.violations == []
     assert eng2.alloc.free_count == eng2.num_pages
+
+
+# -- request stamps, packer counters and profiler spans ----------------------
+
+def test_stamps_ordered_and_kept_across_preemption():
+    """``submit_s <= admit_s <= first_token_s`` on the host clock, and a
+    request preempted mid-decode and readmitted keeps all three first
+    values (``admitted_step`` still moves, for victim choice)."""
+    cfg, params = _qcfg()
+    kw = dict(batch_size=2, max_len=64, page_size=8, prefill_buckets=(8,),
+              prefill_chunk=8, prefill_budget=8, num_pages=4)
+    eng = PagedEngine(cfg, params, audit_every=1, **kw)
+    victim = Request(rid=1, prompt=_prompts([24], seed=5)[0],
+                     max_new_tokens=6)
+    eng.submit(victim)
+    for _ in range(4):                 # 3 chunk steps + 1 decode step
+        eng.step()
+    assert victim.status == Status.RUNNING and 1 <= len(victim.tokens) < 6
+    first = (victim.submit_s, victim.admit_s, victim.first_token_s)
+    assert first[0] <= first[1] <= first[2]
+    hi = Request(rid=2, prompt=_prompts([8], seed=6)[0], max_new_tokens=2,
+                 priority=5)
+    eng.submit(hi)
+    while eng.step():
+        pass
+    assert victim.preemptions >= 1 and victim.done and not victim.failed
+    assert victim.admitted_step > 0                   # readmitted later
+    assert (victim.submit_s, victim.admit_s, victim.first_token_s) == first
+    assert hi.done and first[2] <= hi.submit_s <= hi.admit_s \
+        <= hi.first_token_s
+    assert eng.violations == []
+
+
+def test_packer_counts_prefill_steps_and_stalls():
+    """Two prompts of two chunks each under a budget of one chunk: the
+    older row's chunks launch in steps 0 and 1, the younger waits both
+    steps (two stalls) and launches in steps 2 and 3.  A one-shot
+    admission never prefills through the packer and counts nothing."""
+    cfg, params = _qcfg()
+    kw = dict(batch_size=2, max_len=64, page_size=8, prefill_buckets=(8,),
+              prefill_chunk=8, prefill_budget=8)
+    eng = PagedEngine(cfg, params, audit_every=1, **kw)
+    old, young = (Request(rid=i, prompt=p, max_new_tokens=3)
+                  for i, p in enumerate(_prompts([16, 16], seed=9)))
+    eng.run([old, young])
+    assert old.done and young.done
+    assert (old.prefill_steps, old.prefill_stalls) == (2, 0)
+    assert (young.prefill_steps, young.prefill_stalls) == (4, 2)
+    assert old.admit_s <= young.admit_s
+    assert old.first_token_s < young.first_token_s
+
+    one_shot = PagedEngine(cfg, params, batch_size=2, max_len=64,
+                           page_size=8, prefill_buckets=(16,))
+    req = Request(rid=3, prompt=_prompts([16], seed=9)[0], max_new_tokens=3)
+    one_shot.run([req])
+    assert req.done
+    assert (req.prefill_steps, req.prefill_stalls) == (0, 0)
+    assert req.submit_s <= req.admit_s <= req.first_token_s
+
+
+class _SpanLog:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each span's
+    name, nesting depth and metadata in the order spans open."""
+
+    def __init__(self):
+        self.spans, self.depth = [], 0
+
+    def __call__(self, name, **meta):
+        log = self
+
+        class Span:
+            def __enter__(self):
+                self.meta = dict(meta)
+                log.spans.append((name, log.depth, self.meta))
+                log.depth += 1
+                return self
+
+            def __exit__(self, *exc):
+                log.depth -= 1
+
+            def set_metadata(self, **more):
+                self.meta.update(more)
+
+        return Span()
+
+
+def test_step_spans_in_phase_order(monkeypatch):
+    """One step that admits a one-chunk prompt, launches it and decodes it
+    opens the engine's spans in phase order, each nested under
+    ``engine.step``, with the launch shape and request id on the
+    prefill span."""
+    from repro.launch import engine as engine_mod
+    cfg, params = _qcfg()
+    eng = PagedEngine(cfg, params, batch_size=2, max_len=64, page_size=8,
+                      prefill_buckets=(8,), prefill_chunk=8,
+                      prefill_budget=8)
+    req = Request(rid=7, prompt=_prompts([8], seed=3)[0], max_new_tokens=3)
+    eng.submit(req)
+    log = _SpanLog()
+    monkeypatch.setattr(engine_mod, "TraceAnnotation", log)
+    assert eng.step()
+    assert [(n, d) for n, d, _ in log.spans] == [
+        ("engine.step", 0), ("engine.admit", 1), ("engine.prefill", 1),
+        ("engine.prefill_sync", 1), ("engine.decode", 1),
+        ("engine.decode_sync", 1), ("engine.commit", 1)]
+    meta = {n: m for n, _, m in log.spans}
+    assert meta["engine.step"] == {"step": 0}
+    assert meta["engine.admit"] == {"admitted": 1}
+    assert meta["engine.prefill"] == {"start": 0, "width": 1, "bucket": 8,
+                                      "tokens": 8, "rids": "7"}
+    assert meta["engine.decode"] == {"rows": 1}
+    assert len(req.tokens) == 2 and log.depth == 0

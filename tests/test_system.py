@@ -77,3 +77,8 @@ def test_serving_driver_smoke():
     toks, stats = serve(cfg, params, prompts.astype(jnp.int32), gen_tokens=4)
     assert toks.shape == (2, 4)
     assert stats["tok_per_s"] > 0
+    ttft = [s["ttft_ms"] for s in stats["per_seq"]]
+    assert all(t is not None and t > 0 for t in ttft)
+    assert stats["ttft_p50_ms"] <= stats["ttft_p95_ms"] <= max(ttft)
+    assert not {"decode_s", "prefill_s"} & set(stats)
+    assert all("tok_per_s" not in s for s in stats["per_seq"])
