@@ -94,6 +94,9 @@ _backend = [None if _env_backend is None
             else _checked(_env_backend, "REPRO_KERNEL_BACKEND")]
 
 STATS = {"qlinear_pallas": 0, "qlinear_xla": 0,
+         # qlinear_pallas calls whose N or K is not a multiple of its block
+         # (a partial weight block, where the kernel once padded the weight)
+         "qlinear_ragged": 0,
          "attention_pallas": 0, "attention_decode_pallas": 0,
          "attention_paged_pallas": 0, "attention_paged_xla": 0,
          "attention_xla": 0,
@@ -199,6 +202,12 @@ def qmatmul_blocks(m: int, n: int, k: int, *, packed: bool = False,
     revisits of the output tile), then grow bm/bn toward the MXU sweet spot.
     ``packed`` (nibble weights) rounds bk to 256 so the kernel's bk/2-wide
     nibble-plane tiles stay lane-aligned.
+
+    bn and bk need not divide N and K: on the 128 grid the kernel never pads
+    the weight, and reads the ragged edge as a partial block (exact: the K
+    edge meets zero activation columns, the N edge's extra output columns
+    are dropped).  :func:`maybe_qlinear` counts those calls in
+    ``STATS["qlinear_ragged"]``.
     """
     bk = min(_round_up(k, _LANE), 2048)
     bm = min(_round_up(m, _LANE), 256)
@@ -343,6 +352,8 @@ def maybe_qlinear(x, p: dict, cfg):
         row_scale = None
     bias = p.get("b")
     bm, bn, bk = qmatmul_blocks(x2.shape[0], n, kdim, packed=packed)
+    if n % bn or kdim % bk:
+        STATS["qlinear_ragged"] += 1
     out = qmatmul(x2, w_q, scale,
                   None if bias is None else bias.astype(jnp.float32),
                   row_scale, bm=bm, bn=bn, bk=bk, packed=packed)

@@ -21,6 +21,15 @@ lane dims) and VMEM-light (x: 64KB, w: 64KB int8, acc: 64KB int32).  The
 serving graph overrides them per shape via
 :func:`repro.kernels.dispatch.qmatmul_blocks` (VMEM-budgeted heuristics);
 model graphs reach this kernel through ``dispatch.maybe_qlinear``.
+
+The weight is read where it is stored and never padded or copied in HBM
+when N and K are multiples of 128 (every serving width): the grid is
+``cdiv(N, bn) x cdiv(K, bk)`` and the last N and K blocks are partial blocks
+of the stored weight.  The K edge is exact because X is padded with zero
+columns, so the weight tile's out-of-range columns add 0 to the int32
+accumulator whatever they hold; the N edge is exact because the output
+columns past N are partial-block writes, which are dropped.  Shapes off the
+128 grid pad the weight to the tile.
 """
 from __future__ import annotations
 
@@ -32,6 +41,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import interpret_mode
+
+_LANE = 128                       # lane width: the grid the weight is read on
 
 
 def nibble_planes(packed):
@@ -97,19 +108,22 @@ def qmatmul(x_q, w_q, scale, bias=None, row_scale=None, *, bm=128, bn=128,
     if row_scale is None:
         row_scale = jnp.ones((m,), jnp.float32)
 
-    # Pad to block multiples (static shapes).
-    pm, pn, pk = (-m) % bm, (-n) % bn, (-kdim) % bk
+    # X is zero-padded to the tile.  The weight is padded only off the 128
+    # grid; on it a ragged edge is a partial block (module docstring).
+    pm, pk = (-m) % bm, (-kdim) % bk
+    pn = (-n) % bn if n % _LANE else 0
+    pwk = pk if kdim % _LANE else 0
     if pm or pk:
         x_q = jnp.pad(x_q, ((0, pm), (0, pk)))
-    if pn or pk:
-        w_q = jnp.pad(w_q, ((0, pn), (0, pk // (2 if packed else 1))))
+    if pn or pwk:
+        w_q = jnp.pad(w_q, ((0, pn), (0, pwk // (2 if packed else 1))))
     if pn:
         scale = jnp.pad(scale, (0, pn))
         bias = jnp.pad(bias, (0, pn))
     if pm:
         row_scale = jnp.pad(row_scale, (0, pm))
     mm, nn, kk = m + pm, n + pn, kdim + pk
-    nm, nn_blocks, nk = mm // bm, nn // bn, kk // bk
+    nm, nn_blocks, nk = mm // bm, pl.cdiv(nn, bn), kk // bk
 
     scale2 = scale.reshape(1, nn).astype(jnp.float32)
     bias2 = bias.reshape(1, nn).astype(jnp.float32)

@@ -8,13 +8,17 @@ and every model in the zoo at smoke sizes); longer rows stream on the
 running-m grid and are covered against the streamed oracle in
 test_kernels.py instead.
 """
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import Jaxpr
 
 from repro.core.api import QuantConfig, dense, integerize_params
 from repro.kernels import dispatch
+from repro.kernels.qmatmul import qmatmul
 from repro.layers.attention import AttnSpec, attention
 
 
@@ -114,6 +118,89 @@ def test_dense_fallback_for_stacked_weights():
     p = {"w_q": jnp.zeros((2, 8, 8), jnp.int8), "w_scale": jnp.ones((2, 8))}
     assert not dispatch.qlinear_supported(jnp.zeros((4, 8)), p)
     assert dispatch.maybe_qlinear(jnp.zeros((4, 8)), p, cfg) is None
+
+
+def _pads_of(fn, shape, *args):
+    """The pad equations of ``fn``'s program, nested jaxprs included, whose
+    operand has ``shape``."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name == "pad"
+                    and eqn.invars[0].aval.shape == shape):
+                yield eqn
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if isinstance(sub, Jaxpr):
+                        yield from walk(sub)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["int8", "nibbles"])
+def test_qmatmul_ragged_never_pads_weight(packed):
+    """On the 128 grid a ragged N and K edge copies nothing of the weight;
+    off the grid the weight is still padded (which shows the walk sees)."""
+    kw = dict(bm=128, bn=256, bk=512, packed=packed)
+    for n, k, pads in [(384, 640, 0), (200, 640, 1)]:
+        w = jnp.zeros((n, k // 2 if packed else k),
+                      jnp.uint8 if packed else jnp.int8)
+        found = _pads_of(partial(qmatmul, **kw), w.shape,
+                         jnp.zeros((64, k), jnp.int8), w, jnp.ones((n,)))
+        assert len(found) == pads, (n, k)
+
+
+@pytest.mark.parametrize("n,k,ragged", [
+    (384, 2176, 1),             # N and K ragged on the 128 grid
+    (13696, 512, 1),            # ChatGLM3's gate/up width
+    (512, 2048, 0),             # block multiples: today's grid
+])
+def test_qlinear_ragged_counted_and_unpadded(n, k, ragged):
+    cfg = QuantConfig(w_bits=4, a_bits=8, mode="int", backend="pallas")
+    p = {"w_q": jnp.zeros((n, k), jnp.int8), "w_scale": jnp.ones((n,))}
+    x = jnp.zeros((1, 64, k), jnp.float32)
+    dispatch.reset_stats()
+    found = _pads_of(lambda x, p: dispatch.maybe_qlinear(x, p, cfg),
+                     (n, k), x, p)
+    assert found == []
+    assert dispatch.STATS["qlinear_pallas"] == 1
+    assert dispatch.STATS["qlinear_ragged"] == ragged
+    assert dispatch.snapshot()["qlinear_ragged"] == ragged
+
+
+@pytest.mark.parametrize("batch,seq", [(1, 512), (64, 1)],
+                         ids=["prefill", "decode"])
+def test_chatglm3_ragged_calls_are_the_ffn(batch, seq):
+    """At ChatGLM3-6B's published widths (traced on abstract weights, never
+    built) each program's ragged qmatmul calls are gate, up and down; the
+    attention projections and lm_head divide their tiles."""
+    from repro.configs.registry import get_config
+    from repro.models import lm
+    qc = QuantConfig(w_bits=4, a_bits=8, attn_bits=7, kv_bits=8, mode="int",
+                     backend="pallas")
+    cfg = get_config("chatglm3-6b").replace(quant=qc)
+    params = jax.eval_shape(
+        lambda: lm.init_int_params(jax.random.PRNGKey(0), cfg, qc))
+
+    def logits(p, tokens):
+        x, _, _ = lm.forward(p, {"tokens": tokens}, cfg)
+        return lm.logits_fn(p, x, cfg)
+
+    dispatch.reset_stats()
+    jax.make_jaxpr(logits)(params,
+                           jax.ShapeDtypeStruct((batch, seq), jnp.int32))
+    # wq, wk, wv, wo, gate, up, down once in the scanned unit, and lm_head.
+    assert dispatch.STATS["qlinear_pallas"] == 8
+    assert dispatch.STATS["qlinear_ragged"] == 3
+    unit = params["units"]["b0"]
+    widths = {name: p["w_q"].shape[1:]          # (N, K) of one layer
+              for name, p in [*unit["attn"].items(), *unit["ffn"].items()]}
+    widths["lm_head"] = params["lm_head"]["w_q"].shape
+    ragged = set()
+    for name, (n, k) in widths.items():
+        _, bn, bk = dispatch.qmatmul_blocks(batch * seq, n, k)
+        if n % bn or k % bk:
+            ragged.add(name)
+    assert ragged == {"gate", "up", "down"}
 
 
 # ---------------------------------------------------------------------------

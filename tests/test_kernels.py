@@ -56,6 +56,49 @@ def test_qmatmul_int4_packed_matches_unpacked():
                                rtol=1e-6)
 
 
+# Widths on the 128 grid whose N or K is not a block multiple: the kernel
+# reads the stored weight's edge as a partial block instead of padding it.
+RAGGED_CASES = [
+    (64, 384, 640, 256, 512),        # N and K ragged
+    (64, 640, 1664, 256, 1024),      # d_ff-like: N tail 128, K tail 640
+    (64, 384, 512, 256, 512),        # gate/up-like: N ragged only
+    (64, 256, 640, 256, 512),        # down-like: K ragged only
+]
+
+
+def _ragged_operands(m, n, k):
+    key = jax.random.PRNGKey(m + n + k)
+    x = _rand_int8(key, (m, k), -128, 128)
+    w = _rand_int8(jax.random.fold_in(key, 1), (n, k))
+    scale = jnp.abs(jax.random.normal(jax.random.fold_in(key, 2), (n,))) * .01
+    bias = jax.random.normal(jax.random.fold_in(key, 3), (n,))
+    return x, w, scale, bias
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["int8", "nibbles"])
+@pytest.mark.parametrize("m,n,k,bn,bk", RAGGED_CASES)
+def test_qmatmul_ragged_bit_exact(m, n, k, bn, bk, packed):
+    """Partial weight blocks give the padded product bit for bit."""
+    x, w, scale, bias = _ragged_operands(m, n, k)
+    stored = pack_int4(w) if packed else w
+    kw = dict(bm=128, bn=bn, bk=bk, packed=packed)
+    # Unit scales leave the int32 product itself, exact in f32 at these K.
+    ones = jnp.ones((n,), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(qmatmul(x, stored, ones, **kw)),
+        np.asarray(ref.qmatmul_ref(x, w, ones)))
+    # The same product with both operands explicitly zero-padded to the
+    # tile (what the kernel computed before it read partial blocks).
+    out = qmatmul(x, stored, scale, bias, **kw)
+    pn, pk = (-n) % bn, (-k) % bk
+    w_pad = jnp.pad(w, ((0, pn), (0, pk)))
+    padded = qmatmul(jnp.pad(x, ((0, 0), (0, pk))),
+                     pack_int4(w_pad) if packed else w_pad,
+                     jnp.pad(scale, (0, pn)), jnp.pad(bias, (0, pn)), **kw)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(padded[:, :n]))
+
+
 ATTN_CASES = [
     (2, 128, 128, 64, True, None),
     (2, 100, 260, 64, True, None),       # unaligned

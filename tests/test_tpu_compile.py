@@ -11,6 +11,7 @@ The topology is described inside a module fixture, never at import: only one
 process may load the TPU compiler's library at a time, and every test worker
 imports this file.
 """
+import re
 from functools import partial
 
 import jax
@@ -43,10 +44,19 @@ def _compiled_text(one_chip, fn, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _weight_pads(text, dtype, n):
+    """Result shapes of the pads of a (>= n, ...) array of the weight's
+    dtype: a copy of the weight to the tile."""
+    shapes = re.findall(rf"= {dtype}\[(\d+),(\d+)\]\S* pad\(", text)
+    return [s for s in shapes if int(s[0]) >= n]
+
+
 @pytest.mark.parametrize("packed", [False, True], ids=["int8", "nibbles"])
-@pytest.mark.parametrize("n", [4608, 13696], ids=["qkv", "ffn"])
-def test_qmatmul_compiles(one_chip, n, packed):
-    m, k = 128, D_MODEL
+@pytest.mark.parametrize("n,k", [(4608, D_MODEL), (13696, D_MODEL),
+                                 (D_MODEL, 13696)],
+                         ids=["qkv", "ffn", "down"])
+def test_qmatmul_compiles(one_chip, n, k, packed):
+    m = 128
     bm, bn, bk = dispatch.qmatmul_blocks(m, n, k, packed=packed)
     w = ((n, k // 2), jnp.uint8) if packed else ((n, k), jnp.int8)
     text = _compiled_text(
@@ -55,6 +65,9 @@ def test_qmatmul_compiles(one_chip, n, packed):
         ((m, k), jnp.int8), w, ((n,), jnp.float32), ((n,), jnp.float32),
         ((m,), jnp.float32))
     assert "tpu_custom_call" in text
+    # ffn's N and down's K are not block multiples: the kernel reads the
+    # ragged edge as a partial block, and the weight is never padded.
+    assert _weight_pads(text, "u8" if packed else "s8", n) == []
 
 
 def test_fused_attention_compiles(one_chip):
