@@ -5,9 +5,11 @@ self-checks can drive a whole run at a tiny size on the CPU.  Everything
 that belongs to one configuration, traffic mix or metric lives in a file
 of its own and is found by the name ``BENCHMARK.json`` gives it:
 ``configs/<config>.json`` (sizes, quantization, engine settings and the
-name of the plain reference), ``traffic/<mix>.json`` (read by
-``generator.py``), ``metrics/<metric>.py`` (one reader each) and
-``limits/<cell>.json`` (the limit of each number ``correct`` compares).
+name of the plain reference, which also names the architecture module
+``arch/<name>.py``: its weights, the reference's view of them and its
+work counts), ``traffic/<mix>.json`` (read by ``generator.py``),
+``metrics/<metric>.py`` (one reader each) and ``limits/<cell>.json``
+(the limit of each number ``correct`` compares).
 
 The window drives ``PagedEngine.submit``/``step``, the path ``serve()``
 takes.  The harness wraps its own engine's two jitted programs only to
@@ -29,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import arch
 import check
 import generator
 import trace_reduce
@@ -70,22 +73,17 @@ def reader(name: str):
 # ---------------------------------------------------------------------------
 
 def build(config: dict):
-    """(LMConfig, QuantConfig) of a configuration file."""
+    """(LMConfig, QuantConfig) of a configuration file; the model section
+    is the architecture module's to read (``arch/<name>.py``)."""
     from repro.core.api import QuantConfig
-    from repro.models.lm import LMConfig
-    m, q = config["model"], config["quant"]
+    q = config["quant"]
     qc = QuantConfig(w_bits=q["w_bits"], a_bits=q["a_bits"],
                      attn_bits=q["attn_bits"], kv_bits=q["kv_bits"],
                      mode=q["mode"], softmax=q["softmax"],
                      pack_weights=q["pack_weights"],
                      quantize_embeddings=q["quantize_embeddings"])
-    cfg = LMConfig(name=config["name"], n_layers=m["n_layers"],
-                   d_model=m["d_model"], n_heads=m["n_heads"],
-                   kv_heads=m["kv_heads"], d_ff=m["d_ff"], vocab=m["vocab"],
-                   head_dim=m["head_dim"], rope_theta=m["rope_theta"],
-                   rotary_frac=m["rotary_frac"], qkv_bias=m["qkv_bias"],
-                   norm=m["norm"], act=m["act"], quant=qc)
-    return cfg, qc
+    cfg = arch.of(config).lm_config(config["model"], qc)
+    return cfg.replace(name=config["name"]), qc
 
 
 def warm_shapes(traffic: dict, eng: dict) -> list[tuple[int, int, int]]:
@@ -285,12 +283,13 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     counter = Counter()
     dispatch.set_backend(backend or eng["backend"])
     cfg, qc = build(config)
+    blocks = arch.of(config)
     dev = jax.devices()[0]
 
     # -- weights, made on the device from the seed --------------------------
-    params = weights.make(seed, model, quant)
+    params = blocks.make(seed, model, quant)
     weights.check_layout(
-        weights.shape_of(model, quant),
+        blocks.shape_of(model, quant),
         jax.eval_shape(lambda k: lm.init_int_params(k, cfg, qc),
                        weights.prng_key(0)))
     jax.block_until_ready(params)
@@ -480,7 +479,7 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     picks = check.sample(finished, seed, min_check_tokens,
                          min_check_requests, max_check_requests)
     ref = check.reference(config)
-    top, layer = weights.reference_view(params, model)
+    top, layer = blocks.reference_view(params, model)
     t_ref = time.perf_counter()
 
     if picks:

@@ -24,6 +24,7 @@ CHIP = HERE.parent
 ROOT = CHIP.parents[1]
 sys.path[:0] = [str(CHIP), str(ROOT / "src"), str(ROOT / "benchmarks")]
 
+import arch  # noqa: E402
 import generator  # noqa: E402
 import harness  # noqa: E402
 import trace_reduce  # noqa: E402
@@ -169,7 +170,7 @@ def test_mfu_from_model_ops():
     run.config = config
     ops = work.model_ops(config, calls)
     m = config["model"]
-    block, head = work.linears(m)
+    block, head = arch.of(config).linears(m)
     per_tok = 2 * sum(n * k for n, k in block) * m["n_layers"]
     att = 4 * m["n_heads"] * m["head_dim"] * m["n_layers"]
     expect = (per_tok + 2 * head[0] * head[1] + att * 10) + (
@@ -242,7 +243,7 @@ def test_weights_match_program_layout():
         config = json.loads((ROOT / entry["file"]).read_text())
         cfg, qc = harness.build(config)
         weights.check_layout(
-            weights.shape_of(config["model"], config["quant"]),
+            arch.of(config).shape_of(config["model"], config["quant"]),
             jax.eval_shape(lambda k: lm.init_int_params(k, cfg, qc),
                            weights.prng_key(0)))
 

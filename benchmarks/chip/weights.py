@@ -2,19 +2,20 @@
 
 The benchmark, not the program, makes the weights: one jitted call turns
 ``--seed`` into the integer parameter tree the serving engine reads (w4
-codes one per byte with a float32 step per output row, int8 embedding rows
-with a step per row, bf16 QKV biases, float32 norm gains), so the plain
+codes one per byte with a float32 step per output channel, int8 embedding
+rows with a step per row, bf16 biases, float32 norm gains), so the plain
 reference can be handed the very same codes and steps without taking
-anything the program made.  :func:`check_layout` holds the tree against
-the program's own layout (``jax.eval_shape`` of ``lm.init_int_params``).
+anything the program made.  This module holds the pieces every
+architecture shares; the blocks are laid out by the configuration's
+architecture module (``arch/<name>.py``).  :func:`check_layout` holds the
+tree against the program's own layout (``jax.eval_shape`` of
+``lm.init_int_params``).
 
 Distributions: each weight row is a standard normal row quantized on its
 absmax grid, times ``fan_in ** -0.5``; embedding rows are standard normal
 on an int8 absmax grid; biases ~ N(0, 0.1); norm gains 1 + N(0, 0.1).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -51,46 +52,13 @@ def _gain(key, d):
     return {"gamma": 1.0 + 0.1 * jax.random.normal(key, (d,), jnp.float32)}
 
 
-def _layer(key, m, w_bits):
-    d, hd = m["d_model"], m["head_dim"]
-    hq, hkv, ff = m["n_heads"] * hd, m["kv_heads"] * hd, m["d_ff"]
-    ks = jax.random.split(key, 9)
-    return {
-        "ln1": _gain(ks[0], d),
-        "attn": {"wq": _linear(ks[1], hq, d, w_bits, m["qkv_bias"]),
-                 "wk": _linear(ks[2], hkv, d, w_bits, m["qkv_bias"]),
-                 "wv": _linear(ks[3], hkv, d, w_bits, m["qkv_bias"]),
-                 "wo": _linear(ks[4], d, hq, w_bits, False)},
-        "ln2": _gain(ks[5], d),
-        "ffn": {"gate": _linear(ks[6], ff, d, w_bits, False),
-                "up": _linear(ks[7], ff, d, w_bits, False),
-                "down": _linear(ks[8], d, ff, w_bits, False)},
-    }
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _make(key, frozen_model, w_bits):
-    m = dict(frozen_model)
-    ks = jax.random.split(key, 4)
+def top(keys, vocab: int, d: int, w_bits: int):
+    """Embedding, final norm and head, from three keys."""
     emb_q, emb_step = _codes(
-        jax.random.normal(ks[0], (m["vocab"], m["d_model"]), jnp.float32), 8)
-    units = jax.lax.map(lambda k: {"b0": _layer(k, m, w_bits)},
-                        jax.random.split(ks[1], m["n_layers"]))
+        jax.random.normal(keys[0], (vocab, d), jnp.float32), 8)
     return {"embed": {"emb_q": emb_q, "emb_scale": emb_step},
-            "final_norm": _gain(ks[2], m["d_model"]),
-            "lm_head": _linear(ks[3], m["vocab"], m["d_model"], w_bits, False),
-            "units": units}
-
-
-def _frozen(model: dict):
-    keys = ("n_layers", "d_model", "n_heads", "kv_heads", "head_dim", "d_ff",
-            "vocab", "qkv_bias")
-    return tuple((k, model[k]) for k in keys)
-
-
-def make(seed: int, model: dict, quant: dict):
-    """The serving parameter tree of ``model`` for ``seed``, on the device."""
-    return _make(prng_key(seed), _frozen(model), quant["w_bits"])
+            "final_norm": _gain(keys[1], d),
+            "lm_head": _linear(keys[2], vocab, d, w_bits, False)}
 
 
 def check_layout(params_shape, program_shape):
@@ -108,42 +76,21 @@ def check_layout(params_shape, program_shape):
                            f"parameter layout: {diff}")
 
 
-def shape_of(model: dict, quant: dict):
-    return jax.eval_shape(lambda k: _make(k, _frozen(model),
-                                          quant["w_bits"]), prng_key(0))
-
-
 # ---------------------------------------------------------------------------
 # The reference's view of the same weights
 # ---------------------------------------------------------------------------
 
-def reference_view(params, model: dict):
-    """Callables the reference reads its weights through: ``top()`` and
-    ``layer(l)``, each a dict of (codes, step[, bias]) per linear and the
-    float gains.  Nothing is dequantized here."""
-    if model["rope_layout"] != "interleaved":
-        raise ValueError(f"the program rotates interleaved pairs; rope "
-                         f"layout {model['rope_layout']!r} is not served")
+def reference_linear(p):
+    """A linear layer as the reference reads it: codes, step, bias."""
+    out = {"codes": p["w_q"], "step": p["w_scale"]}
+    if "b" in p:
+        out["bias"] = p["b"]
+    return out
 
-    def lin(p):
-        out = {"codes": p["w_q"], "step": p["w_scale"]}
-        if "b" in p:
-            out["bias"] = p["b"]
-        return out
 
-    def top():
-        return {"emb_codes": params["embed"]["emb_q"],
-                "emb_step": params["embed"]["emb_scale"],
-                "final_gain": params["final_norm"]["gamma"],
-                "head": lin(params["lm_head"])}
-
-    def layer(l):
-        u = jax.tree_util.tree_map(lambda x: x[l], params["units"]["b0"])
-        a = u["attn"]
-        return {"ln1": u["ln1"]["gamma"], "ln2": u["ln2"]["gamma"],
-                "wq": lin(a["wq"]), "wk": lin(a["wk"]),
-                "wv": lin(a["wv"]), "wo": lin(a["wo"]),
-                "gate": lin(u["ffn"]["gate"]), "up": lin(u["ffn"]["up"]),
-                "down": lin(u["ffn"]["down"])}
-
-    return top, layer
+def reference_top(params):
+    """Embedding, final gain and head, as the reference reads them."""
+    return {"emb_codes": params["embed"]["emb_q"],
+            "emb_step": params["embed"]["emb_scale"],
+            "final_gain": params["final_norm"]["gamma"],
+            "head": reference_linear(params["lm_head"])}

@@ -5,7 +5,10 @@ pads or repeats: real (unpadded) prompt tokens, active decode rows, the
 keys a query may attend, the pages that hold them.  So a change that
 replaces a kernel cannot make its roofline share read over 100%.
 
-The formulas copy ``benchmarks/kernel_bench.py``'s analytic functions
+The per-kernel formulas are here; which kernels a model calls, how often
+and at what sizes is its architecture module's (``arch/<name>.py``),
+which :func:`kernel_calls` and :func:`model_ops` pass to.  The formulas
+copy ``benchmarks/kernel_bench.py``'s analytic functions
 (``qmatmul_analytic``: ``m*k + n*k*w_bytes + m*n*4`` bytes, ``m*n*k``
 MACs; ``paged_step_analytic``: K and V bytes of each row's live pages;
 ``attention_macs``: two int8 contractions per query-key pair);
@@ -15,6 +18,8 @@ multiply or an add, so one MAC is two operations.
 from __future__ import annotations
 
 import math
+
+import arch
 
 
 def qmatmul(m: int, n: int, k: int, w_bytes: float):
@@ -49,20 +54,21 @@ def fused_prefill(lens, hq: int, hkv: int, d: int):
     return ops, byts
 
 
-def linears(model: dict):
-    """(n, k) of every linear layer in one block, then of the head."""
-    d, hd = model["d_model"], model["head_dim"]
-    hq, hkv, ff = model["n_heads"] * hd, model["kv_heads"] * hd, model["d_ff"]
-    block = [(hq, d), (hkv, d), (hkv, d), (d, hq), (ff, d), (ff, d), (d, ff)]
-    return block, (model["vocab"], d)
+def weight_bytes(quant: dict) -> float:
+    """Bytes a stored weight takes: w4 codes packed two to a byte, else
+    one code a byte."""
+    return 0.5 if quant["pack_weights"] and quant["w_bits"] == 4 else 1.0
 
 
-def _decode_rows(call):
+def decode_rows(call):
+    """Live key counts of the active rows of a recorded decode call."""
     pos = call[2]
     return [int(p) + 1 for p in pos if p >= 0]
 
 
-def _prefill_rows(call):
+def prefill_rows(call):
+    """(start, real lengths of the rows) of a recorded prefill launch;
+    no lengths where the run did not read them back."""
     start, w, s, lens = call[2]
     if lens is None:                # lengths are read in traced runs only
         return start, []
@@ -71,57 +77,12 @@ def _prefill_rows(call):
 
 def kernel_calls(config: dict, calls, kernel: str):
     """Yield (ops, bytes) for every call of ``kernel`` that the program
-    calls in ``calls`` made (``harness.Recorder`` entries)."""
-    m, q, eng = config["model"], config["quant"], config["engine"]
-    w_bytes = 0.5 if q["pack_weights"] and q["w_bits"] == 4 else 1.0
-    kv_bytes = q["kv_bits"] / 8
-    block, head = linears(m)
-    hq, hkv, d, L = m["n_heads"], m["kv_heads"], m["head_dim"], m["n_layers"]
-    for call in calls:
-        if call[0] == "decode":
-            live = _decode_rows(call)
-            if not live:
-                continue
-            if kernel == "qmatmul":
-                for _ in range(L):
-                    for n, k in block:
-                        yield qmatmul(len(live), n, k, w_bytes)
-                yield qmatmul(len(live), *head, w_bytes)
-            elif kernel == "paged_decode":
-                for _ in range(L):
-                    yield paged_decode(live, hq, hkv, d, eng["page_size"],
-                                       kv_bytes)
-        else:
-            start, lens = _prefill_rows(call)
-            if not lens:
-                continue
-            if kernel == "qmatmul":
-                for _ in range(L):
-                    for n, k in block:
-                        yield qmatmul(sum(lens), n, k, w_bytes)
-                yield qmatmul(len(lens), *head, w_bytes)
-            elif kernel == "fused_prefill" and start == 0:
-                for _ in range(L):
-                    yield fused_prefill(lens, hq, hkv, d)
+    calls in ``calls`` made (``harness.Recorder`` entries), as the
+    configuration's architecture module counts them."""
+    return arch.of(config).kernel_calls(config, calls, kernel)
 
 
 def model_ops(config: dict, calls) -> float:
-    """Operations the model needs for the work in ``calls``: two per
-    weight per token through every linear layer (the head only where the
-    program takes logits), plus QK and PV over the keys each query may
-    attend."""
-    m = config["model"]
-    block, head = linears(m)
-    per_tok = 2 * sum(n * k for n, k in block) * m["n_layers"]
-    head_ops = 2 * head[0] * head[1]
-    att = 2 * 2 * m["n_heads"] * m["head_dim"] * m["n_layers"]
-    total = 0.0
-    for call in calls:
-        if call[0] == "decode":
-            live = _decode_rows(call)
-            total += len(live) * (per_tok + head_ops) + att * sum(live)
-        else:
-            start, lens = _prefill_rows(call)
-            total += sum(per_tok * n + att * causal_pairs(start, n)
-                         for n in lens) + head_ops * len(lens)
-    return total
+    """Operations the model needs for the work in ``calls``, as the
+    configuration's architecture module counts them."""
+    return arch.of(config).model_ops(config, calls)
